@@ -57,6 +57,12 @@ Variants mirror Figure 2:
                   process because forcing the device count only works
                   before the first jax import
 
+This is a host-overhead benchmark: ``run()`` pins itself and every
+child it starts (actor processes, learner workers, the SPMD child) to
+the host CPU, so no variant ever opens an accelerator, and none of its
+frames/sec is a device number. Timing the chip is the job of the
+on-chip benchmark.
+
 Besides the CSV rows, the run writes ``BENCH_throughput.json`` (variant
 -> frames/sec plus run metadata) so the perf trajectory is tracked
 across PRs instead of only printed. ``BENCH_ENVS`` (comma-separated)
@@ -324,6 +330,10 @@ def _write_json(fps_by_env, wire_by_env, replay_by_env,
 
 
 def run() -> None:
+    # host-overhead benchmark: this process and (through the
+    # environment) every child it starts stay on the CPU
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    jax.config.update("jax_platforms", "cpu")
     iters = 5 if FAST else 20
     # all async variants at the same actor count so the thread-vs-process
     # (and unroll-vs-inference-service) comparisons are apples to apples

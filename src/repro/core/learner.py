@@ -308,8 +308,9 @@ def build_spmd_train_step(arch_cfg: ArchConfig, cfg: ImpalaConfig,
     over identical gradients, and the update equals the single-device
     fused step. Callers jit the result with ``donate_argnums=(0, 1)``.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
+
+    from repro.sharding.compat import shard_map
 
     if optimizer is None:
         optimizer = opt_lib.rmsprop(decay=cfg.rmsprop_decay,
@@ -337,7 +338,7 @@ def build_spmd_train_step(arch_cfg: ArchConfig, cfg: ImpalaConfig,
     bspec = P() if batch_replicated else P("data")
     train_step = shard_map(local_step, mesh=mesh,
                            in_specs=(P(), P(), P(), bspec),
-                           out_specs=(P(), P(), P()))
+                           out_specs=(P(), P(), P()), check_vma=False)
     return train_step, optimizer
 
 
@@ -357,8 +358,9 @@ def build_spmd_replay_train_step(arch_cfg: ArchConfig, cfg: ImpalaConfig,
     shard_map output spec reassembles the global vector, so replay
     re-prioritization sees every trajectory. Callers jit with
     ``donate_argnums=(0, 2)`` (the target is a long-lived snapshot)."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
+
+    from repro.sharding.compat import shard_map
 
     if optimizer is None:
         optimizer = opt_lib.rmsprop(decay=cfg.rmsprop_decay,
@@ -388,7 +390,7 @@ def build_spmd_replay_train_step(arch_cfg: ArchConfig, cfg: ImpalaConfig,
     bspec = P() if batch_replicated else P("data")
     smapped = shard_map(local_step, mesh=mesh,
                         in_specs=(P(), P(), P(), P(), bspec),
-                        out_specs=(P(), P(), P(), bspec))
+                        out_specs=(P(), P(), P(), bspec), check_vma=False)
 
     def train_step(params, target_params, opt_state, step, batch):
         params, opt_state, metrics, traj_adv = smapped(

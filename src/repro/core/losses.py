@@ -27,6 +27,21 @@ def resolve_vtrace_impl(impl: str = "auto") -> str:
     return "fused" if jax.default_backend() == "tpu" else "scan"
 
 
+def resolve_loss_impl(cfg: ImpalaConfig, impl: str = "auto",
+                      replay: bool = False) -> str:
+    """The V-trace implementation ``impala_loss`` actually runs for
+    ``cfg``. The fused kernel computes only the plain V-trace loss: the
+    ablation variants and the replay path (target-network baseline,
+    per-trajectory advantages) keep their dedicated math and drop to
+    the plain V-trace kernel on TPU, the scan elsewhere."""
+    impl = resolve_vtrace_impl(impl)
+    if impl == "fused" and (
+            replay or cfg.correction != "vtrace" or
+            getattr(cfg, "pg_q_estimate", "vtrace") == "baseline_v"):
+        impl = "pallas" if jax.default_backend() == "tpu" else "scan"
+    return impl
+
+
 def reward_clip(rewards: jax.Array, mode: str) -> jax.Array:
     if mode == "abs_one":
         return jnp.clip(rewards, -1.0, 1.0)
@@ -87,18 +102,12 @@ def impala_loss(cfg: ImpalaConfig, target_logits, values, batch: Dict,
     ``per_traj=True`` adds ``vtrace/traj_adv_mag`` (B,), the
     per-trajectory |pg advantage| mean — the replay priority signal.
     """
-    impl = resolve_vtrace_impl(impl)
+    impl = resolve_loss_impl(
+        cfg, impl, replay=corr_values is not None or per_traj)
     rewards = reward_clip(batch["rewards"], cfg.reward_clip)
-    if impl == "fused" and corr_values is None and not per_traj:
-        if (cfg.correction == "vtrace" and
-                getattr(cfg, "pg_q_estimate", "vtrace") != "baseline_v"):
-            return _impala_loss_fused(cfg, target_logits, values, batch,
-                                      rewards)
     if impl == "fused":
-        # ablation variants (and the replay baseline/per-traj paths)
-        # keep their dedicated math; drop to the plain V-trace kernel
-        # for whatever scan they do use
-        impl = "pallas" if jax.default_backend() == "tpu" else "scan"
+        return _impala_loss_fused(cfg, target_logits, values, batch,
+                                  rewards)
     vs, pg_adv = corrections.compute_correction(
         cfg, batch["behaviour_logprob"], target_logits, batch["actions"],
         batch["discounts"], rewards,

@@ -1383,6 +1383,17 @@ def run_group_training(
     if not isinstance(env_name, str):
         raise ValueError("learner-group workers rebuild the env by "
                          "name; pass an env name, not an Env object")
+    if num_learners > 1:
+        import jax
+        platform = jax.default_backend()
+        if platform != "cpu":
+            # each worker would open this host's accelerator, which one
+            # process owns: the second would fail or hang on it
+            raise ValueError(
+                f"{num_learners} learner processes would share this "
+                f"host's {platform} devices, which one process owns; "
+                f"use --learner-mode spmd --spmd-devices N (one process "
+                f"training data-parallel over the devices) instead")
     if transport is None:
         transport = {"process": "shm",
                      "remote": "socket"}.get(actor_backend, "inproc")

@@ -446,6 +446,16 @@ class Learner:
             # --learners 1 must bit-match the single-learner run
             params = pcommon.init_params(specs, jax.random.key(seed))
         replay_on = icfg.replay_fraction > 0.0
+        # what the loss will run, resolved now so telemetry shows it:
+        # the implementation, and whether its Pallas kernel is
+        # interpreted (never on a TPU unless forced)
+        from repro.core.losses import resolve_loss_impl
+        from repro.kernels.vtrace import resolve_interpret
+        impl = resolve_loss_impl(icfg, vtrace_impl, replay=replay_on)
+        self.vtrace_resolved = {
+            "impl": impl,
+            "interpret": (impl in ("fused", "pallas")
+                          and resolve_interpret())}
         spmd_on = exchange is not None and getattr(exchange, "in_xla",
                                                    False)
         self._spmd_mesh = None
@@ -720,6 +730,7 @@ class Learner:
                                       self.store.version),
             "actor_mode": self.actor_mode,
             "donate": self.donate,
+            "vtrace": dict(self.vtrace_resolved),
         }
         if "inference" in col:
             snap["inference"] = col["inference"]

@@ -417,7 +417,11 @@ def remote_actor_child(address, stop_event) -> None:
     exception") when the interpreter exits with runtime threads still
     live — turning a perfectly clean run into a nonzero exit code at
     random. The error path already reported its traceback over the
-    ctrl link; the exit code only needs to be honest."""
+    ctrl link; the exit code only needs to be honest. The child acts
+    on the host CPU: the chip belongs to the learner that spawned it."""
     import os
+
+    from repro.distributed.runner import use_host_cpu
+    use_host_cpu()
     err = remote_actor_main(tuple(address), stop_event)
     os._exit(0 if err is None else 1)

@@ -702,6 +702,15 @@ def run_serialized_inference_actor(*, actor_id: int, env_name: str,
 # process worker entry point (spawn target — must be module-level)
 
 
+def use_host_cpu() -> None:
+    """Run this process's JAX on the host CPU, whatever platform its
+    environment names. Every spawned actor child calls this before its
+    first JAX use: a chip belongs to one process, and the learner that
+    spawned the child holds it."""
+    import jax
+    jax.config.update("jax_platforms", "cpu")
+
+
 def _tune_child_scheduling(actor_id: int) -> None:
     """Best-effort OS tuning for an actor child on a shared box: actors
     yield to the learner (the learner is the throughput constraint under
@@ -751,6 +760,7 @@ def process_actor_main(actor_id: int, env_name: str, arch_cfg, icfg,
     subscriber, and sender all live in ``run_serialized_unroll_actor``,
     shared verbatim with the socket (remote) backend."""
     try:
+        use_host_cpu()
         _tune_child_scheduling(actor_id)
 
         def pull_msg(have_version):
@@ -789,6 +799,7 @@ def inference_actor_main(actor_id: int, env_name: str, arch_cfg, icfg,
     ``run_serialized_inference_actor``, shared verbatim with the socket
     (remote) backend."""
     try:
+        use_host_cpu()
         _tune_child_scheduling(actor_id)
         run_serialized_inference_actor(
             actor_id=actor_id, env_name=env_name, arch_cfg=arch_cfg,

@@ -54,8 +54,11 @@ def _vtrace_kernel(rho_ref, c_ref, disc_ref, rew_ref, v_ref, vtp1_ref,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
+    # every loop value is a (1, b_block) row — batch on lanes. Mosaic
+    # refuses a rank-1 carry (its layouts are at least 2-D), so rows
+    # are sliced with pl.ds(s, 1) rather than indexed with a scalar
     def body(i, acc):
-        s = t_chunk - 1 - i
+        s = pl.ds(t_chunk - 1 - i, 1)
         rho = rho_ref[s, :]
         disc = disc_ref[s, :]
         rew = rew_ref[s, :]
@@ -67,8 +70,7 @@ def _vtrace_kernel(rho_ref, c_ref, disc_ref, rew_ref, v_ref, vtp1_ref,
         vs_ref[s, :] = v + acc
         return acc
 
-    acc = jax.lax.fori_loop(0, t_chunk, body, acc_ref[0, :])
-    acc_ref[0, :] = acc
+    acc_ref[...] = jax.lax.fori_loop(0, t_chunk, body, acc_ref[...])
 
 
 def vtrace_pallas(rho, c, discounts, rewards, values, values_tp1,
@@ -115,12 +117,17 @@ def vtrace_pallas(rho, c, discounts, rewards, values, values_tp1,
 # target log-probs, entropy terms, the clipped importance weights, and
 # the V-trace reverse scan — instead of ~10 separate XLA ops feeding the
 # recurrence. Same layout discipline as ``vtrace_pallas`` (time-major,
-# batch on lanes, reversed T chunks with a VMEM-carried accumulator);
-# the action dimension rides whole in each block, padded to the 128-wide
-# lane multiple with a large negative logit so softmax ignores the pad.
+# batch on lanes, reversed T chunks with a VMEM-carried accumulator).
+# The action dimension rides whole in each block ON SUBLANES — the
+# kernel sees logits as (T, A, B) — so the softmax reductions over
+# actions land as (1, b_block) rows with the batch still on lanes, the
+# layout every output is stored in. Actions pad to the 8-sublane
+# multiple (not the 128-lane one) with a large negative logit so softmax
+# ignores the pad: chase's 5 actions cost 8 rows, not 128 lanes, and a
+# (100, 8, 128) f32 block is 400 KiB, far inside the default scoped VMEM.
 
 _NEG_PAD = -1e30     # pad logit: exp underflows to exactly 0 in f32
-LANE = 128
+SUBLANE = 8
 
 
 def _loss_vtrace_kernel(logits_ref, onehot_ref, blp_ref, disc_ref,
@@ -134,15 +141,16 @@ def _loss_vtrace_kernel(logits_ref, onehot_ref, blp_ref, disc_ref,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     def body(i, acc):
-        s = t_chunk - 1 - i
-        row = logits_ref[s, :, :]                    # (b_block, A)
-        m = jnp.max(row, axis=-1, keepdims=True)
-        logp = row - m - jnp.log(
-            jnp.sum(jnp.exp(row - m), axis=-1, keepdims=True))
-        tlp = jnp.sum(logp * onehot_ref[s, :, :], axis=-1)
+        t = t_chunk - 1 - i
+        s = pl.ds(t, 1)
+        lg = logits_ref[t]                           # (A, b_block)
+        m = jnp.max(lg, axis=0, keepdims=True)
+        logp = lg - m - jnp.log(
+            jnp.sum(jnp.exp(lg - m), axis=0, keepdims=True))
+        tlp = jnp.sum(logp * onehot_ref[t], axis=0, keepdims=True)
         p = jnp.exp(logp)
         tlp_ref[s, :] = tlp
-        ne_ref[s, :] = jnp.sum(p * logp, axis=-1)
+        ne_ref[s, :] = jnp.sum(p * logp, axis=0, keepdims=True)
         rho_raw = jnp.exp(tlp - blp_ref[s, :])
         rho = (jnp.minimum(rho_bar, rho_raw)
                if rho_bar is not None else rho_raw)
@@ -158,8 +166,7 @@ def _loss_vtrace_kernel(logits_ref, onehot_ref, blp_ref, disc_ref,
         vs_ref[s, :] = v + acc
         return acc
 
-    acc = jax.lax.fori_loop(0, t_chunk, body, acc_ref[0, :])
-    acc_ref[0, :] = acc
+    acc_ref[...] = jax.lax.fori_loop(0, t_chunk, body, acc_ref[...])
 
 
 def loss_vtrace_pallas(logits, onehot, behaviour_logprob, discounts,
@@ -182,23 +189,26 @@ def loss_vtrace_pallas(logits, onehot, behaviour_logprob, discounts,
     b_block = min(b_block, b)
     tp = (-t) % t_chunk
     bp = (-b) % b_block
-    ap = (-a) % LANE
+    ap = (-a) % SUBLANE
     flat = (behaviour_logprob, discounts, rewards, values, values_tp1)
     if tp or bp:
         flat = tuple(jnp.pad(x, ((0, tp), (0, bp))) for x in flat)
     if tp or bp or ap:
-        # pad rows get uniform log-probs over real lanes (tlp = onehot
+        # pad rows get uniform log-probs over real actions (tlp = onehot
         # sum = 0 against a zero onehot), zero rewards/discounts/values:
         # the carried accumulator stays exactly zero through them
         logits = jnp.pad(logits, ((0, tp), (0, bp), (0, ap)),
                          constant_values=_NEG_PAD)
         onehot = jnp.pad(onehot, ((0, tp), (0, bp), (0, ap)))
+    # actions to sublanes, batch to lanes (see the layout note above)
+    logits = jnp.swapaxes(logits, 1, 2)
+    onehot = jnp.swapaxes(onehot, 1, 2)
     tt, bb, aa = t + tp, b + bp, a + ap
     nt, nb = tt // t_chunk, bb // b_block
 
     spec2d = pl.BlockSpec((t_chunk, b_block), lambda i, j: (nt - 1 - j, i))
-    spec3d = pl.BlockSpec((t_chunk, b_block, aa),
-                          lambda i, j: (nt - 1 - j, i, 0))
+    spec3d = pl.BlockSpec((t_chunk, aa, b_block),
+                          lambda i, j: (nt - 1 - j, 0, i))
     tlp, ne, vs, pg = pl.pallas_call(
         functools.partial(_loss_vtrace_kernel, t_chunk=t_chunk,
                           rho_bar=rho_bar, c_bar=c_bar, lambda_=lambda_),

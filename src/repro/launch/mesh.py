@@ -10,15 +10,28 @@ module never touches jax device state — only ``launch/dryrun.py`` sets
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 from repro.configs.base import MeshConfig
 from repro.sharding.rules import Rules
 
 
+def auto_mesh(shape, axes, devices=None) -> jax.sharding.Mesh:
+    """``jax.make_mesh`` with every axis ``Auto``. The installed jax
+    defaults to ``Explicit`` axes, which ``with_sharding_constraint``
+    (``sharding/rules.py``) and the mesh-wide device assignment of
+    ``device_put`` refuse; every mesh of this repo is built here.
+    ``devices`` (e.g. a described topology's) defaults to the local
+    pool."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
+
+
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def make_mesh_2d_tp(*, multi_pod: bool = False) -> jax.sharding.Mesh:
@@ -28,11 +41,11 @@ def make_mesh_2d_tp(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     shape = (2, 16, 4, 4) if multi_pod else (16, 4, 4)
     axes = (("pod", "data", "model_a", "model_b") if multi_pod
             else ("data", "model_a", "model_b"))
-    return jax.make_mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def make_mesh(cfg: MeshConfig) -> jax.sharding.Mesh:
-    return jax.make_mesh(cfg.shape, cfg.axis_names)
+    return auto_mesh(cfg.shape, cfg.axis_names)
 
 
 def make_data_mesh(num_devices: int) -> jax.sharding.Mesh:
@@ -48,7 +61,7 @@ def make_data_mesh(num_devices: int) -> jax.sharding.Mesh:
             f"spmd mesh needs 1..{avail} devices, got {num_devices} "
             f"(on CPU, set XLA_FLAGS=--xla_force_host_platform_"
             f"device_count={num_devices} before the first jax import)")
-    return jax.make_mesh((num_devices,), ("data",))
+    return auto_mesh((num_devices,), ("data",))
 
 
 def make_rules(mesh: jax.sharding.Mesh, overrides=None) -> Rules:

@@ -24,21 +24,55 @@ CPU-scale entry points (real envs, real learning):
       --actor-backend process --transport shm --env catch \
       --steps 100 --smoke
 
+On a TPU the learner (and thread actors, and the inference service)
+run on the chip; spawned actor children run on the host CPU, since a
+chip belongs to one process. ``chip_smoke.py`` at the repository root
+drives this entry point on a chip.
+
 The production mesh path for the assigned architectures is exercised by
-``repro.launch.dryrun`` (compile-only on this CPU-only box).
+``repro.launch.dryrun`` (compile-only).
 """
 from __future__ import annotations
 
 import argparse
 import json
+import os
 import time
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+# the checkout's own persistent compile cache (listed in .gitignore); a
+# fixed path, because the path is part of what a cache hit matches
+CACHE_DIR = os.path.normpath(os.path.join(
+    os.path.dirname(os.path.abspath(__file__)), "..", "..", "..",
+    ".jax_cache"))
 
-def main() -> int:
+
+def enable_compile_cache():
+    """Turn on JAX's persistent compilation cache for a TPU run; returns
+    its directory, or None where it stays off. A
+    ``JAX_COMPILATION_CACHE_DIR`` from the environment is JAX's to use as
+    it stands, and nothing else is set; otherwise a TPU run caches at
+    ``CACHE_DIR``. Other backends compile this repo's programs in
+    seconds, and XLA:CPU warns on every cached executable it loads.
+    Call before the first compile (it initializes the backend), never
+    at import."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    if jax.default_backend() != "tpu":
+        return None
+    jax.config.update("jax_compilation_cache_dir", CACHE_DIR)
+    return CACHE_DIR
+
+
+def main(argv=None, on_update=None) -> int:
+    """The training CLI. ``argv`` defaults to ``sys.argv[1:]``;
+    ``on_update(step, params, metrics, snapshot_fn)``, when given, also
+    sees every async learner update (single learner), so a caller in
+    this process can check what the run produced."""
     p = argparse.ArgumentParser()
     p.add_argument("--arch", default="impala-shallow")
     p.add_argument("--env", default="catch")
@@ -269,7 +303,7 @@ def main() -> int:
                           "this path while training")
     obs.add_argument("--sink-interval-s", type=float, default=5.0,
                      help="seconds between --telemetry-sink lines")
-    args = p.parse_args()
+    args = p.parse_args(argv)
 
     if args.connect:
         # remote actor mode: this process contributes actors to a
@@ -293,6 +327,7 @@ def main() -> int:
               f"coordinator={args.coord_addr} "
               f"devices={jax.device_count()} "
               f"(local {jax.local_device_count()})")
+    enable_compile_cache()
 
     if args.learner_mode == "spmd":
         if args.runtime != "async":
@@ -325,7 +360,7 @@ def main() -> int:
         reward_clip=args.reward_clip, seed=args.seed)
 
     if args.runtime == "async":
-        return _run_async(args, env, arch, icfg)
+        return _run_async(args, env, arch, icfg, on_update)
     return _run_sync(args, env, arch, icfg)
 
 
@@ -370,7 +405,6 @@ def _run_remote_actors(args) -> int:
     print(f"remote actor mode: {n} actor process(es) -> "
           f"{addr[0]}:{addr[1]}")
     if n == 1:
-        import os
         from repro.distributed.netserve import remote_actor_main
         err = remote_actor_main(addr)
         if err:
@@ -474,7 +508,7 @@ def _run_sync(args, env, arch, icfg) -> int:
     return 0
 
 
-def _run_async(args, env, arch, icfg) -> int:
+def _run_async(args, env, arch, icfg, caller_on_update=None) -> int:
     from repro.checkpoint import checkpoint as ckpt
     from repro.distributed import run_async_training
     from repro.models import backbone as bb
@@ -550,6 +584,8 @@ def _run_async(args, env, arch, icfg) -> int:
             # legacy params-only saves; --supervise switches to the
             # runtime's combined fleet-v1 checkpoints instead
             ckpt.save(args.ckpt_dir, step, params)
+        if caller_on_update is not None:
+            caller_on_update(step, params, metrics, snapshot_fn)
 
     env_arg = (args.env if args.actor_backend in ("process", "remote")
                else env)
@@ -583,7 +619,7 @@ def _run_async(args, env, arch, icfg) -> int:
     print(f"final return(100) = {tracker.mean_return():.3f}")
     keys = ["learner_updates", "frames_consumed", "updates_per_sec",
             "frames_per_sec", "batch_size_hist", "lag", "queue",
-            "actors", "param_version"]
+            "actors", "param_version", "vtrace"]
     if "inference" in tel:
         keys.append("inference")
     if "replay" in tel:

@@ -8,9 +8,9 @@ wall-clock stamped) even when nobody was curling /metrics.
 ``ProfileHook`` wraps ``jax.profiler`` around a chosen train-step
 window (``--profile-steps A:B``): the trace starts before step A's
 update and stops after step B's, producing a TensorBoard-loadable
-profile directory. Failures (profiler unavailable, trace dir not
-writable) disable the hook with a one-line note instead of killing
-training.
+profile directory. A profiler that fails to start or stop (profiler
+unavailable, trace dir not writable) fails the run: a traced run
+without its trace has not measured what it was started for.
 """
 from __future__ import annotations
 
@@ -89,25 +89,17 @@ class ProfileHook:
         if self.done:
             return
         if not self.active and self.lo <= next_update <= self.hi:
-            try:
-                import jax
-                jax.profiler.start_trace(self.out_dir)
-                self.active = True
-                print(f"[obs] jax.profiler tracing updates "
-                      f"[{self.lo}, {self.hi}] -> {self.out_dir}",
-                      flush=True)
-            except Exception as e:
-                print(f"[obs] profiling disabled: {e!r}", flush=True)
-                self.done = True
+            import jax
+            jax.profiler.start_trace(self.out_dir)
+            self.active = True
+            print(f"[obs] jax.profiler tracing updates "
+                  f"[{self.lo}, {self.hi}] -> {self.out_dir}", flush=True)
         elif self.active and next_update > self.hi:
             self.stop()
 
     def stop(self) -> None:
-        if self.active:
-            try:
-                import jax
-                jax.profiler.stop_trace()
-            except Exception as e:
-                print(f"[obs] profiler stop failed: {e!r}", flush=True)
-            self.active = False
         self.done = True
+        if self.active:
+            self.active = False     # never stop twice, even on error
+            import jax
+            jax.profiler.stop_trace()

@@ -1,32 +1,18 @@
-"""shard_map across jax versions.
+"""The one call site of ``jax.shard_map``.
 
-``shard_map`` moved from ``jax.experimental.shard_map`` to the top-level
-``jax.shard_map`` (and its replication-check keyword was renamed
-``check_rep`` -> ``check_vma``) across jax releases. Every caller in this
-repo goes through :func:`shard_map` below so the codebase runs on both
-API generations.
+Every ``shard_map`` of this repo (the SPMD learner steps in
+``core/learner.py``, the expert-parallel MoE in ``models/moe.py``) goes
+through :func:`shard_map` below, so a change of the API or of its
+defaults lands in one place.
 """
 from __future__ import annotations
 
 import jax
 
-try:
-    _shard_map = jax.shard_map
-    _CHECK_KW = "check_vma"
-except AttributeError:  # older jax: experimental API, check_rep keyword
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _CHECK_KW = "check_rep"
-
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma=None):
-    """Version-agnostic ``jax.shard_map``.
-
-    ``check_vma`` (new name; maps onto ``check_rep`` on older jax) is only
-    forwarded when explicitly given, so each jax version keeps its own
-    default.
-    """
-    kwargs = {}
-    if check_vma is not None:
-        kwargs[_CHECK_KW] = check_vma
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, **kwargs)
+    """``jax.shard_map``; ``check_vma`` is forwarded only when given, so
+    jax keeps its own default otherwise."""
+    kwargs = {} if check_vma is None else {"check_vma": check_vma}
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, **kwargs)

@@ -16,6 +16,7 @@ SCRIPT = textwrap.dedent("""
     from repro.configs.base import ImpalaConfig
     from repro.configs.registry import get_smoke_config
     from repro.core import learner as learner_lib
+    from repro.launch.mesh import auto_mesh
     from repro.models import backbone as bb, common
     from repro.sharding.rules import Rules, use_rules
 
@@ -42,7 +43,7 @@ SCRIPT = textwrap.dedent("""
     losses["single"] = float(m["loss/total"])
     ref_leaf = np.asarray(jax.tree.leaves(p1)[0], np.float32)
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = auto_mesh((2, 4), ("data", "model"))
     for profile in [None, {"embed": ("data", "model"), "heads": None,
                            "kv_heads": None, "ff": None, "vocab": None,
                            "batch": ("data", "model")}]:

@@ -705,8 +705,18 @@ def run_spmd(pick):
 pC_dup = run_spmd(lambda h0, h1: (h0, h0))
 pC_dist = run_spmd(lambda h0, h1: (h0, h1))
 
+
+def max_rel_diff(a, b):
+    return max(float(np.max(np.abs(np.asarray(x, np.float64) -
+                                   np.asarray(y, np.float64))) /
+                     max(float(np.max(np.abs(np.asarray(y, np.float64)))),
+                         1e-30))
+               for x, y in zip(jax.tree.leaves(a), jax.tree.leaves(b)))
+
+
 print(json.dumps({
     "A": digest(pA),
+    "A_vs_B_dup": max_rel_diff(pA, dup[0]),
     "B_dup": [digest(dup[0]), digest(dup[1])],
     "B_dist": [digest(dist[0]), digest(dist[1])],
     "C_dup": digest(pC_dup),
@@ -723,9 +733,11 @@ def test_spmd_group_single_digest_triangle_subprocess():
 
     * dup halves (both shards carry the same trajectories): the spmd
       shard_map step == both replicas of a real hub/spoke 2-learner
-      group == the single fused learner, bit-identical — the in-XLA
-      pmean over identical shards is the identity, like the group's
-      wire mean of identical gradients;
+      group, bit-identical — the in-XLA pmean over identical shards is
+      the identity, like the group's wire mean of identical gradients
+      — and both equal the single fused learner to float32 rounding
+      (one program fusing backward and update may round an element
+      differently from the split grad/apply programs);
     * distinct halves: spmd on concat(h0, h1) == the hub/spoke group
       training one learner per half — pmean of per-shard sum-gradients
       is exactly the hub's mean, so swapping the TCP exchange for the
@@ -737,9 +749,11 @@ def test_spmd_group_single_digest_triangle_subprocess():
                        capture_output=True, text=True, env=env, timeout=400)
     assert r.returncode == 0, r.stderr[-2000:]
     out = json.loads(r.stdout.strip().splitlines()[-1])
-    # dup: all three legs collapse to one digest
+    # dup: the group and spmd legs collapse to one digest, and the
+    # single fused learner agrees to float32 rounding
     assert out["B_dup"][0] == out["B_dup"][1], out
-    assert out["A"] == out["B_dup"][0] == out["C_dup"], out
+    assert out["B_dup"][0] == out["C_dup"], out
+    assert out["A_vs_B_dup"] <= 1e-6, out
     # distinct: group replicas identical, and spmd matches them
     assert out["B_dist"][0] == out["B_dist"][1], out
     assert out["C_dist"] == out["B_dist"][0], out
@@ -747,3 +761,14 @@ def test_spmd_group_single_digest_triangle_subprocess():
     assert out["C_dist"] != out["C_dup"], out
     # hub versions delegate round_idx + 1, matching CollectiveExchange
     assert out["versions"] == [1, 2, 3], out
+
+
+def test_learner_group_refuses_to_share_an_accelerator(monkeypatch):
+    """On an accelerator host N learner processes would all open the
+    devices one process owns: the group fails before spawning anything,
+    pointing at the single-process SPMD learner."""
+    import jax
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(ValueError, match="--learner-mode spmd"):
+        run_group_training("bandit", _icfg(), 4, 2, num_learners=2)

@@ -77,6 +77,22 @@ def test_losses_vtrace_impl_auto_resolution():
         assert resolve_vtrace_impl(explicit) == explicit
 
 
+def test_resolve_loss_impl_keeps_fused_only_for_the_plain_vtrace_loss():
+    from repro.configs.base import ImpalaConfig
+    from repro.core.losses import resolve_loss_impl
+
+    cfg = ImpalaConfig(num_actions=5)
+    plain = "pallas" if jax.default_backend() == "tpu" else "scan"
+    assert resolve_loss_impl(cfg, "fused") == "fused"
+    # replay (target baseline, per-trajectory advantages) and the
+    # ablation corrections keep their own math
+    assert resolve_loss_impl(cfg, "fused", replay=True) == plain
+    assert resolve_loss_impl(
+        ImpalaConfig(num_actions=5, correction="onestep_is"),
+        "fused") == plain
+    assert resolve_loss_impl(cfg, "scan", replay=True) == "scan"
+
+
 # ---------------------------------------------------------------------------
 # fused loss/V-trace kernel
 

@@ -144,9 +144,10 @@ def test_lower_pair_end_to_end_subprocess():
         from repro.configs.registry import get_smoke_config
         from repro.launch import steps as steps_lib
         from repro.roofline import analysis
+        from repro.launch.mesh import auto_mesh
         from repro.sharding.rules import Rules
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = auto_mesh((2, 4), ("data", "model"))
         rules = Rules(mesh)
         out = {}
         for name in ["stablelm_1_6b", "olmoe_1b_7b", "mamba2_1_3b"]:

@@ -358,6 +358,34 @@ def test_jsonl_sink_writes_lines(tmp_path):
                for ln in lines)
 
 
+def test_profile_hook_failures_fail_the_run(monkeypatch, tmp_path):
+    """A profiler that cannot start or stop raises out of the hook (and
+    so out of the learner loop) instead of leaving a run that exits 0
+    without the trace it was started for."""
+    import jax
+
+    from repro.obs.sink import ProfileHook
+
+    def refuse(*a, **k):
+        raise RuntimeError("profiler unavailable")
+
+    monkeypatch.setattr(jax.profiler, "start_trace", refuse)
+    hook = ProfileHook("1:2", str(tmp_path))
+    hook.on_step(0)                 # before the window: nothing to do
+    with pytest.raises(RuntimeError, match="unavailable"):
+        hook.on_step(1)
+
+    monkeypatch.setattr(jax.profiler, "start_trace", lambda d: None)
+    monkeypatch.setattr(jax.profiler, "stop_trace", refuse)
+    hook = ProfileHook("1:2", str(tmp_path))
+    hook.on_step(1)
+    assert hook.active
+    with pytest.raises(RuntimeError, match="unavailable"):
+        hook.on_step(3)
+    hook.stop()                     # already stopped: no second raise
+    assert hook.done and not hook.active
+
+
 def test_parse_profile_steps():
     assert parse_profile_steps("3:10") == (3, 10)
     assert parse_profile_steps("0:0") == (0, 0)

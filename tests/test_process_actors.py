@@ -139,3 +139,25 @@ def test_thread_and_process_backends_both_learn_on_catch():
         assert late > -0.3, (backend, early, late)
     # the serialized run really crossed the wire
     assert results["process"][2]["queue"]["wire_received"] > 0
+
+
+def test_use_host_cpu_overrides_the_inherited_platform():
+    """Spawned actor children inherit the learner's environment, which
+    on a chip host names the accelerator; ``use_host_cpu`` (the first
+    thing every child runs) must still put the child's JAX on the CPU,
+    leaving the chip to the learner."""
+    import os
+    import subprocess
+    import sys
+
+    code = ("from repro.distributed.runner import use_host_cpu\n"
+            "use_host_cpu()\n"
+            "import jax\n"
+            "print(jax.devices()[0].platform)\n")
+    env = dict(os.environ, JAX_PLATFORMS="tpu",
+               PYTHONPATH=os.path.join(os.path.dirname(__file__), "..",
+                                       "src"))
+    r = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                       text=True, env=env, timeout=300)
+    assert r.returncode == 0, r.stderr[-2000:]
+    assert r.stdout.strip().splitlines()[-1] == "cpu"

@@ -20,7 +20,8 @@ from repro.sharding.rules import Rules
 @pytest.fixture(scope="module")
 def mesh():
     # 1x1 mesh on the single CPU device: resolution logic is identical
-    return jax.make_mesh((1, 1), ("data", "model"))
+    from repro.launch.mesh import auto_mesh
+    return auto_mesh((1, 1), ("data", "model"))
 
 
 def _spec_with_sizes(mesh_shape=(1, 1)):
@@ -85,10 +86,11 @@ SUBPROCESS_EQUIV = textwrap.dedent("""
     import dataclasses, json, jax, jax.numpy as jnp, numpy as np
     from repro.configs.registry import get_smoke_config
     from repro.models import moe as moe_lib, common
+    from repro.launch.mesh import auto_mesh
     from repro.sharding.rules import Rules, use_rules
 
     cfg = get_smoke_config("olmoe_1b_7b")
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = auto_mesh((2, 4), ("data", "model"))
     rules = Rules(mesh)
     specs = moe_lib.moe_specs(cfg)
     params = common.init_params(specs, jax.random.key(0))
