@@ -51,6 +51,7 @@ import numpy as np
 from repro.distributed import serde
 from repro.distributed.paramstore import ParameterStore
 from repro.models import backbone as bb
+from repro.obs.trace import span
 
 PyTree = Any
 
@@ -327,53 +328,54 @@ class InferenceService:
         # flushes) and on leader client threads (full-bucket flushes) —
         # only the RNG advance and the jit cache need the lock, the
         # flush execution itself is free-threaded
-        k = len(batch)
-        # partial batches pad up to the power-of-two bucket by repeating
-        # the last request (its duplicate replies are discarded): jit
-        # variants stay log2-bounded and a phase-coherent partial batch
-        # (e.g. 3 of 4 actors, the 4th mid-assembly) flushes whole
-        # instead of splitting into pow2 shards
-        kb = min(_pow2_ceil(k), self.max_batch_requests)
-        self._warm_buckets(batch[0].data)
-        with self._lock:
-            fn = self._flush_fns[kb]
-            self._flush_seq += 1
-            seq = self._flush_seq
-        params, version = self._store.pull()
-        now = time.monotonic()
-        reqs = [p.data for p in batch] + [batch[-1].data] * (kb - k)
-        # materialize ONCE: the flush must complete before any reply is
-        # usable, and numpy row slices are free views — handing out lazy
-        # device slices instead makes every client pay its own forced
-        # execution (~1ms each, measured) on its critical path
-        action, logp, h, c = (np.asarray(x) for x in
-                              fn(params, np.int64(seq), tuple(reqs)))
+        with span("infer.flush"):
+            k = len(batch)
+            # partial batches pad up to the power-of-two bucket by repeating
+            # the last request (its duplicate replies are discarded): jit
+            # variants stay log2-bounded and a phase-coherent partial batch
+            # (e.g. 3 of 4 actors, the 4th mid-assembly) flushes whole
+            # instead of splitting into pow2 shards
+            kb = min(_pow2_ceil(k), self.max_batch_requests)
+            self._warm_buckets(batch[0].data)
+            with self._lock:
+                fn = self._flush_fns[kb]
+                self._flush_seq += 1
+                seq = self._flush_seq
+            params, version = self._store.pull()
+            now = time.monotonic()
+            reqs = [p.data for p in batch] + [batch[-1].data] * (kb - k)
+            # materialize ONCE: the flush must complete before any reply is
+            # usable, and numpy row slices are free views — handing out lazy
+            # device slices instead makes every client pay its own forced
+            # execution (~1ms each, measured) on its critical path
+            action, logp, h, c = (np.asarray(x) for x in
+                                  fn(params, np.int64(seq), tuple(reqs)))
 
-        with self._lock:        # snapshot() reads these concurrently
-            self.batch_hist[k] += 1
-            if reason == "full":
-                self.flush_full += 1
-            elif reason == "ready":
-                self.flush_ready += 1
-            else:
-                self.flush_timeouts += 1
-            self._c_requests.inc(k)
-            self.padded_requests += kb - k
-            self._last_version = version
+            with self._lock:        # snapshot() reads these concurrently
+                self.batch_hist[k] += 1
+                if reason == "full":
+                    self.flush_full += 1
+                elif reason == "ready":
+                    self.flush_ready += 1
+                else:
+                    self.flush_timeouts += 1
+                self._c_requests.inc(k)
+                self.padded_requests += kb - k
+                self._last_version = version
+                for p in batch:
+                    self._c_frames.inc(p.data["last_action"].shape[0])
+                    self.wait_hist[_wait_bucket(now - p.submitted_at)] += 1
+            off = 0
             for p in batch:
-                self._c_frames.inc(p.data["last_action"].shape[0])
-                self.wait_hist[_wait_bucket(now - p.submitted_at)] += 1
-        off = 0
-        for p in batch:
-            b = p.data["last_action"].shape[0]
-            reply = InferenceReply(action[off:off + b], logp[off:off + b],
-                                   (h[off:off + b], c[off:off + b]),
-                                   version)
-            off += b
-            try:
-                p.reply_fn(reply)
-            except Exception as e:      # a dead pipe must not kill a flush
-                self.errors.append(e)
+                b = p.data["last_action"].shape[0]
+                reply = InferenceReply(action[off:off + b], logp[off:off + b],
+                                       (h[off:off + b], c[off:off + b]),
+                                       version)
+                off += b
+                try:
+                    p.reply_fn(reply)
+                except Exception as e:      # a dead pipe must not kill a flush
+                    self.errors.append(e)
 
     # ------------------------------------------------------------------
     # submission + thread frontend

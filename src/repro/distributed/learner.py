@@ -55,6 +55,7 @@ from repro.core import replay as replay_lib
 from repro.distributed.paramstore import ParameterStore
 from repro.distributed.serde import TrajectoryItem
 from repro.obs.metrics import Registry
+from repro.obs.trace import span
 
 PyTree = Any
 
@@ -874,23 +875,50 @@ class Learner:
         self.queue.requeue_front(first)
 
     def _update_once(self, batch, jnp, jax, timings=None):
-        """One training update on ``batch``: fused when alone, split
-        backward/exchange/apply when grouped. Returns (published
-        params, metrics) or None when the exchange shut down.
+        """One training update on ``batch`` and its publish. Returns
+        (published params, metrics) or None when the exchange shut
+        down.
 
         ``timings`` (a dict, flight-recorder runs only) receives
-        step0/step1/published stamps. On the fused path these bracket
-        the async *dispatch* — blocking for the device would tax the
-        pipeline the recorder exists to observe; the split path's
-        ``np.asarray`` already forces the backward pass, so its stamps
-        are real."""
+        step0/step1/published stamps; the ``learner.step`` and
+        ``learner.publish`` profiler spans open and close at the same
+        points. On the fused path these bracket the async *dispatch* —
+        blocking for the device would tax the pipeline the recorder
+        exists to observe; the split path's ``np.asarray`` already
+        forces the backward pass, so its stamps are real."""
+        if timings is not None:
+            timings["step0"] = time.monotonic()
+        with span("learner.step"):
+            stepped = self._step(batch, jnp, jax)
+        if stepped is None:
+            return None
+        published, metrics, version = stepped
+        if timings is not None:
+            timings["step1"] = time.monotonic()
+        with span("learner.publish"):
+            if version is None:
+                self.store.publish(published)
+            else:
+                # versioned publish delegation: the exchange's
+                # designated publisher numbers the rounds; every
+                # learner's store publishes at exactly that version, so
+                # the group's actors observe one monotonic version
+                # stream no matter which learner they pull from
+                self.store.publish_at(published, version)
+        if timings is not None:
+            timings["published"] = time.monotonic()
+        return published, metrics
+
+    def _step(self, batch, jnp, jax):
+        """The update itself: fused when alone, split
+        backward/exchange/apply when grouped. Returns (params to
+        publish, metrics, the version to publish them at — None for the
+        store's next) or None when the exchange shut down."""
         if self._spmd_mesh is not None:
             # SPMD: the whole group update is ONE donated shard_map
             # dispatch — backward, in-XLA pmean, optimizer. Nothing
             # crosses the host, so the exchange only delegates the
             # version number and books the round.
-            if timings is not None:
-                timings["step0"] = time.monotonic()
             t0 = time.monotonic()
             step_fn = self._spmd_step_for(batch)
             if self._replay is not None:
@@ -915,15 +943,8 @@ class Learner:
             jax.block_until_ready(metrics["opt/grad_norm"])
             self._exchange.observe_round_s(time.monotonic() - t0,
                                            round_idx=self.updates)
-            if timings is not None:
-                timings["step1"] = time.monotonic()
-            self.store.publish_at(published, version)
-            if timings is not None:
-                timings["published"] = time.monotonic()
-            return published, metrics
+            return published, metrics, version
         if self._exchange is None:
-            if timings is not None:
-                timings["step0"] = time.monotonic()
             if self._replay is not None:
                 self._params, self._opt_state, metrics = self._train_step(
                     self._params, self._target_params, self._opt_state,
@@ -934,14 +955,7 @@ class Learner:
                     batch)
             published = (self._snapshot(self._params) if self.donate
                          else self._params)
-            if timings is not None:
-                timings["step1"] = time.monotonic()
-            self.store.publish(published)
-            if timings is not None:
-                timings["published"] = time.monotonic()
-            return published, metrics
-        if timings is not None:
-            timings["step0"] = time.monotonic()
+            return published, metrics, None
         if self._replay is not None:
             grads, metrics = self._grad_step(self._params,
                                              self._target_params, batch)
@@ -962,17 +976,7 @@ class Learner:
         metrics.update(ametrics)
         published = (self._snapshot(self._params) if self.donate
                      else self._params)
-        if timings is not None:
-            timings["step1"] = time.monotonic()
-        # versioned publish delegation: the exchange's designated
-        # publisher numbers the rounds; every learner's store publishes
-        # at exactly that version, so the group's actors observe one
-        # monotonic version stream no matter which learner they pull
-        # from
-        self.store.publish_at(published, version)
-        if timings is not None:
-            timings["published"] = time.monotonic()
-        return published, metrics
+        return published, metrics, version
 
     def _sample_replay(self, num_fresh: int, version_now: int):
         """Plan and draw the replayed top-up for a batch of
@@ -1072,40 +1076,44 @@ class Learner:
                 if should_stop is not None and should_stop():
                     break
                 self._raise_worker_errors()
-                item = self.queue.get(timeout=0.5)
+                with span("learner.wait"):
+                    item = self.queue.get(timeout=0.5)
                 if item is None:
                     continue
                 t_deq = time.monotonic() if want_t else 0.0
-                # replay caps fresh collection below the top bucket —
-                # the batch is topped back up with replayed rows, which
-                # is exactly where the env-frame saving comes from
-                items = _collect_batch(self.queue, self._buckets, item,
-                                       self.batch_linger_s,
-                                       max_items=self._fresh_max)
-                k = len(items)
-                t_col = time.monotonic() if want_t else 0.0
+                with span("learner.stage"):
+                    # replay caps fresh collection below the top bucket —
+                    # the batch is topped back up with replayed rows,
+                    # which is exactly where the env-frame saving comes
+                    # from
+                    items = _collect_batch(self.queue, self._buckets, item,
+                                           self.batch_linger_s,
+                                           max_items=self._fresh_max)
+                    k = len(items)
+                    t_col = time.monotonic() if want_t else 0.0
 
-                version_now = self.store.version
-                for it in items:
-                    self.lag_hist[version_now - it.param_version] += 1
-                    self.tracker.update(it.actor_id, it.data["rewards"],
-                                        it.data["done"])
-                samples = self._sample_replay(k, version_now)
-                train_items = ([s.item for s in samples] + items
-                               if samples else items)
-                if want_t:
-                    self._stager.last_device_put_s = 0.0
-                batch = _stack(train_items, self._stager)
-                if self._replay is not None:
-                    # replayed rows sit FIRST in the stacked batch; the
-                    # mask rides as data so every bucket keeps a single
-                    # compiled program
-                    n_rep = len(samples) if samples else 0
-                    mask = np.zeros(len(train_items) * self._num_envs,
-                                    np.float32)
-                    mask[:n_rep * self._num_envs] = 1.0
-                    batch = dict(batch)
-                    batch["replay_mask"] = mask
+                    version_now = self.store.version
+                    for it in items:
+                        self.lag_hist[version_now - it.param_version] += 1
+                        self.tracker.update(it.actor_id,
+                                            it.data["rewards"],
+                                            it.data["done"])
+                    samples = self._sample_replay(k, version_now)
+                    train_items = ([s.item for s in samples] + items
+                                   if samples else items)
+                    if want_t:
+                        self._stager.last_device_put_s = 0.0
+                    batch = _stack(train_items, self._stager)
+                    if self._replay is not None:
+                        # replayed rows sit FIRST in the stacked batch;
+                        # the mask rides as data so every bucket keeps a
+                        # single compiled program
+                        n_rep = len(samples) if samples else 0
+                        mask = np.zeros(len(train_items) * self._num_envs,
+                                        np.float32)
+                        mask[:n_rep * self._num_envs] = 1.0
+                        batch = dict(batch)
+                        batch["replay_mask"] = mask
                 t_stk = time.monotonic() if want_t else 0.0
                 if self._profile is not None:
                     self._profile.on_step(self.updates)

@@ -39,6 +39,8 @@ import time
 import traceback
 from typing import Any, Callable, List, Optional, Tuple
 
+from repro.obs.trace import span
+
 PyTree = Any
 
 
@@ -90,16 +92,19 @@ def run_actor_loop(
         idx += 1
         sampled = bool(trace_every) and idx % trace_every == 0
         u0 = time.monotonic() if sampled else 0.0
-        carry, traj = unroll(params, carry)
-        # materialise before enqueue: backpressure must reflect finished
-        # work, not a ballooning async dispatch queue
-        traj = jax.block_until_ready(traj)
+        with span("acting.unroll"):
+            carry, traj = unroll(params, carry)
+            # materialise before enqueue: backpressure must reflect
+            # finished work, not a ballooning async dispatch queue
+            traj = jax.block_until_ready(traj)
         if on_unroll is not None:
             on_unroll()
         now = time.monotonic()
         tr = {"u0": u0, "u1": now} if sampled else None
         item = TrajectoryItem(traj, version, actor_id, now, tr)
-        if not emit(item):
+        with span("acting.emit"):
+            emitted = emit(item)
+        if not emitted:
             break
 
 
@@ -435,32 +440,38 @@ def run_inference_driver_loop(
             a.version = None
             a.ukey = jax.random.fold_in(a.key, unroll_idx)
         for t in range(t_len):
-            for a in actors:
-                a.handle = service.submit_async(_acting_request(a))
-                if a.handle is None:
-                    return                  # service shut down
-            service.drive_flushes()
-            for a in actors:
-                if not a.handle.event.is_set():     # frontend raced us
-                    reply = service.wait(a.handle)
-                else:
-                    reply = a.handle.slot[0]
-                if reply is None:
-                    return
-                _record_reply_and_step(a, reply, step_batch, t, conv)
+            with span("acting.step"):
+                for a in actors:
+                    a.handle = service.submit_async(_acting_request(a))
+                    if a.handle is None:
+                        return              # service shut down
+                service.drive_flushes()
+                with span("acting.env_step"):
+                    for a in actors:
+                        if not a.handle.event.is_set():  # frontend raced
+                            reply = service.wait(a.handle)
+                        else:
+                            reply = a.handle.slot[0]
+                        if reply is None:
+                            return
+                        _record_reply_and_step(a, reply, step_batch, t,
+                                               conv)
 
         for a in actors:
             # env-step leaves recorded above may still be lazy device
             # values: assemble_inference_traj forces them (free views
             # by now — the flushes consumed their upstream chains)
-            traj = assemble_inference_traj(a.steps, _acting_boot(a),
-                                           init_lstm[a.uid], icfg)
+            with span("acting.assemble"):
+                traj = assemble_inference_traj(a.steps, _acting_boot(a),
+                                               init_lstm[a.uid], icfg)
             if on_unroll is not None:
                 on_unroll(a.uid)
             now = time.monotonic()
             tr = {"u0": u0, "u1": now} if sampled else None
-            if not emit(a.uid, TrajectoryItem(traj, a.version, a.uid,
-                                              now, tr)):
+            with span("acting.emit"):
+                emitted = emit(a.uid, TrajectoryItem(traj, a.version,
+                                                     a.uid, now, tr))
+            if not emitted:
                 return
 
 

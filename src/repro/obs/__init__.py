@@ -15,7 +15,9 @@ import this package before paying the jax import, like the transports):
             encode -> transport -> queue wait -> batch collect -> train
             step -> publish), stamped across process/socket boundaries
             and normalized to the learner's clock, exported as Chrome
-            trace-event JSON (loadable in Perfetto / chrome://tracing).
+            trace-event JSON (loadable in Perfetto / chrome://tracing);
+            and ``span``, the program's always-on host spans
+            (``HOST_SPAN_NAMES``) on a ``jax.profiler`` trace.
   http      a background stdlib HTTP server next to the learner serving
             ``/metrics`` (Prometheus text format), ``/healthz``
             (ok / degraded / unhealthy), and ``/telemetry`` (live JSON).
@@ -33,7 +35,8 @@ import dataclasses
 from typing import Optional, Tuple
 
 from repro.obs.metrics import Counter, Gauge, IntHistogram, Registry  # noqa: F401
-from repro.obs.trace import SPAN_NAMES, TraceRecorder  # noqa: F401
+from repro.obs.trace import (HOST_SPAN_NAMES, SPAN_NAMES,  # noqa: F401
+                             TraceRecorder, span)
 
 
 @dataclasses.dataclass

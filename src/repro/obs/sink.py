@@ -8,9 +8,13 @@ wall-clock stamped) even when nobody was curling /metrics.
 ``ProfileHook`` wraps ``jax.profiler`` around a chosen train-step
 window (``--profile-steps A:B``): the trace starts before step A's
 update and stops after step B's, producing a TensorBoard-loadable
-profile directory. A profiler that fails to start or stop (profiler
-unavailable, trace dir not writable) fails the run: a traced run
-without its trace has not measured what it was started for.
+profile directory. It records the device planes and the host's
+annotations — the program spans of ``repro.obs.trace``
+(``HOST_SPAN_NAMES``) among them — and no Python tracer: jax's default
+would add an event for every Python call. A profiler that fails to
+start or stop (profiler unavailable, trace dir not writable) fails the
+run: a traced run without its trace has not measured what it was
+started for.
 """
 from __future__ import annotations
 
@@ -74,6 +78,17 @@ def parse_profile_steps(spec: str) -> Tuple[int, int]:
     return lo, hi
 
 
+def profile_options():
+    """The profiler's options: device planes and host annotations
+    (``host_tracer_level`` 1), no Python tracer."""
+    import jax
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    return opts
+
+
 class ProfileHook:
     """Start/stop ``jax.profiler`` around updates [A, B]."""
 
@@ -90,7 +105,8 @@ class ProfileHook:
             return
         if not self.active and self.lo <= next_update <= self.hi:
             import jax
-            jax.profiler.start_trace(self.out_dir)
+            jax.profiler.start_trace(self.out_dir,
+                                     profiler_options=profile_options())
             self.active = True
             print(f"[obs] jax.profiler tracing updates "
                   f"[{self.lo}, {self.hi}] -> {self.out_dir}", flush=True)

@@ -32,6 +32,47 @@ Export is Chrome trace-event JSON (``{"traceEvents": [...]}``, complete
 "X" events, microsecond timestamps) — loadable in Perfetto or
 chrome://tracing. Each actor renders as its own process row; the
 learner's spans render under the learner row.
+
+Program spans
+-------------
+
+Separately from the sampled lifecycle above, the hot loops open
+``span(name)`` — a ``jax.profiler.TraceAnnotation`` — around their host
+work, always, with no flag. With no profiler running one costs about a
+microsecond; under a profiler (``--profile-steps A:B``, or any
+``jax.profiler`` trace) each lands on the profiler's host plane, on the
+same clock as the device planes, so an idle stretch of the chip can be
+put down to the host work that filled it. The names
+(``HOST_SPAN_NAMES``):
+
+    acting.step       one step of the thread-mode inference driver:
+                      every logical actor's submit, the flush, every
+                      env-step dispatch
+    acting.env_step   nested in acting.step: the replies taken and the
+                      env steps dispatched
+    acting.assemble   one inference-mode trajectory packed on the host
+    acting.emit       one trajectory handed to the transport, with its
+                      backpressure retries (driver and unroll actors)
+    acting.unroll     one unroll actor's jitted unroll, to
+                      ``block_until_ready``
+    infer.flush       one inference flush, from picking the bucket to
+                      the last reply handed out (params pull, dispatch,
+                      and the wait for the device)
+    learner.wait      the learner blocked in ``queue.get``
+    learner.stage     first trajectory in hand to the batch staged:
+                      collect, bookkeeping, replay, stack and its
+                      ``device_put`` (a lone trajectory goes to the
+                      device inside the step's call)
+    learner.step      the update (on the fused path, its dispatch)
+    learner.publish   the parameter publish
+
+The learner's spans open and close where ``Learner._record_obs`` takes
+its stamps, so ``phases``, this module's lifecycle and a profile cut the
+loop in the same places. On the fused single-learner path the
+``train_step`` span here and ``phases.step`` (like ``learner.step``)
+time the step's *dispatch*, not its device work; a profile's device
+plane has the device time. Spans opened in actor processes land in
+those processes' own profiler sessions, not the learner's.
 """
 from __future__ import annotations
 
@@ -50,6 +91,23 @@ EXCHANGE_SPAN_NAMES = ("hub_wait", "reduce", "broadcast")
 # same-box monotonic clocks agree to microseconds; a send->receive gap
 # beyond this means a different clock domain (another machine)
 CLOCK_SKEW_S = 5.0
+
+HOST_SPAN_NAMES = ("acting.step", "acting.env_step", "acting.assemble",
+                   "acting.emit", "acting.unroll", "infer.flush",
+                   "learner.wait", "learner.stage", "learner.step",
+                   "learner.publish")
+_HOST_SPANS = frozenset(HOST_SPAN_NAMES)
+
+
+def span(name: str):
+    """A ``jax.profiler.TraceAnnotation`` for one of ``HOST_SPAN_NAMES``
+    (a context manager). jax is imported here, on first use, so this
+    package stays importable without it."""
+    if name not in _HOST_SPANS:
+        raise ValueError(f"{name!r} is not in HOST_SPAN_NAMES")
+    from jax.profiler import TraceAnnotation
+
+    return TraceAnnotation(name)
 
 
 class TraceRecorder:

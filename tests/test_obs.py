@@ -16,8 +16,8 @@ from repro.obs import ObsConfig
 from repro.obs.http import MetricsServer, health, render_prometheus
 from repro.obs.metrics import Counter, Gauge, IntHistogram, Registry
 from repro.obs.sink import JsonlSink, parse_profile_steps
-from repro.obs.trace import (EXCHANGE_SPAN_NAMES, SPAN_NAMES,
-                             TraceRecorder)
+from repro.obs.trace import (EXCHANGE_SPAN_NAMES, HOST_SPAN_NAMES,
+                             SPAN_NAMES, TraceRecorder, span)
 
 
 # ---------------------------------------------------------------------------
@@ -375,7 +375,7 @@ def test_profile_hook_failures_fail_the_run(monkeypatch, tmp_path):
     with pytest.raises(RuntimeError, match="unavailable"):
         hook.on_step(1)
 
-    monkeypatch.setattr(jax.profiler, "start_trace", lambda d: None)
+    monkeypatch.setattr(jax.profiler, "start_trace", lambda d, **kw: None)
     monkeypatch.setattr(jax.profiler, "stop_trace", refuse)
     hook = ProfileHook("1:2", str(tmp_path))
     hook.on_step(1)
@@ -384,6 +384,39 @@ def test_profile_hook_failures_fail_the_run(monkeypatch, tmp_path):
         hook.on_step(3)
     hook.stop()                     # already stopped: no second raise
     assert hook.done and not hook.active
+
+
+def test_profile_hook_traces_annotations_without_the_python_tracer(
+        monkeypatch, tmp_path):
+    """The profile carries the host's annotations (the program spans)
+    and no Python tracer, whose event per Python call jax turns on by
+    default."""
+    import jax
+
+    from repro.obs.sink import ProfileHook
+
+    calls = []
+    monkeypatch.setattr(jax.profiler, "start_trace",
+                        lambda d, **kw: calls.append((d, kw)))
+    monkeypatch.setattr(jax.profiler, "stop_trace", lambda: None)
+    hook = ProfileHook("0:0", str(tmp_path))
+    hook.on_step(0)
+    hook.stop()
+    [(out_dir, kw)] = calls
+    opts = kw["profiler_options"]
+    assert out_dir == str(tmp_path)
+    assert opts.python_tracer_level == 0 and opts.host_tracer_level == 1
+
+
+def test_span_is_a_profiler_annotation_of_a_known_name():
+    import jax
+
+    assert len(set(HOST_SPAN_NAMES)) == len(HOST_SPAN_NAMES)
+    for name in HOST_SPAN_NAMES:
+        with span(name) as s:
+            assert isinstance(s, jax.profiler.TraceAnnotation)
+    with pytest.raises(ValueError, match="HOST_SPAN_NAMES"):
+        span("acting.stepp")
 
 
 def test_parse_profile_steps():

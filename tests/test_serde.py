@@ -236,12 +236,12 @@ def test_module_imports_without_jax():
          "repro.distributed.socket_transport, "
          "repro.distributed.netserve, "
          "repro.distributed.learner, "
-         "repro.distributed.group; sys.exit(1 if 'jax' in "
+         "repro.distributed.group, repro.obs; sys.exit(1 if 'jax' in "
          "sys.modules else 0)"],
         env=env, timeout=120)
     assert r.returncode == 0, \
-        "serde/transport/socket/netserve/learner/group import pulled " \
-        "jax in"
+        "serde/transport/socket/netserve/learner/group/obs import " \
+        "pulled jax in"
 
 
 # ---------------------------------------------------------------------------
