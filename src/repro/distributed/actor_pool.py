@@ -23,6 +23,8 @@ import threading
 import time
 from typing import Dict, List, Optional
 
+import numpy as np
+
 from repro.core import actor as actor_lib
 from repro.distributed.paramstore import ParameterStore
 from repro.distributed.runner import (run_actor_loop,
@@ -56,6 +58,9 @@ class PoolAccounting:
         self.frames = [0] * num_actors          # env frames produced
         self.trajectories = [0] * num_actors    # accepted into the queue
         self.rejected = [0] * num_actors        # lost (rejected/evicted)
+        # inference trajectories by where assemble_inference_traj
+        # stacked their frames
+        self.assembled = {"device": 0, "host": 0}
         self._acct_lock = threading.Lock()
         self._steady_t0: Optional[float] = None
         self._steady_frames0 = 0
@@ -67,6 +72,16 @@ class PoolAccounting:
     def _note_loss(self, item: TrajectoryItem) -> None:
         with self._acct_lock:
             self.rejected[item.actor_id - self.slot_base] += 1
+
+    def _note_assembly(self, item: TrajectoryItem) -> None:
+        """Count one inference-mode trajectory by where its frames were
+        stacked: a device array only when the acting loop kept the env
+        outputs on the device. Serialized children convert every step's
+        outputs to numpy, so what they send was stacked on the host."""
+        where = ("host" if isinstance(item.data["obs_image"], np.ndarray)
+                 else "device")
+        with self._acct_lock:
+            self.assembled[where] += 1
 
     def _note_frames(self, idx: int) -> None:
         self.frames[idx] += self._frames_per_traj
@@ -94,6 +109,8 @@ class PoolAccounting:
             "rejected_per_actor": list(self.rejected),
             "actor_fps": fps,
             "frames_per_actor": list(self.frames),
+            "assembled_on_device": self.assembled["device"],
+            "assembled_on_host": self.assembled["host"],
         }
 
 
@@ -172,6 +189,10 @@ class ActorPool(PoolAccounting):
             attempt += 1
         return False
 
+    def _emit_assembled(self, actor_id: int, item: TrajectoryItem) -> bool:
+        self._note_assembly(item)
+        return self._emit(actor_id - self.slot_base, item)
+
     def _run(self, idx: int, epoch: int = 0) -> None:
         try:
             run_actor_loop(
@@ -212,8 +233,7 @@ class ActorPool(PoolAccounting):
                 num_envs=self.num_envs,
                 seed=fold_restart_seed(self.seed, epoch),
                 service=self.service,
-                emit=lambda aid, item: self._emit(aid - self.slot_base,
-                                                  item),
+                emit=self._emit_assembled,
                 should_stop=self._stop.is_set,
                 on_unroll=lambda aid: self._note_frames(
                     aid - self.slot_base))

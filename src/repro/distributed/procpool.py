@@ -121,6 +121,8 @@ class ProcessActorPool(PoolAccounting):
     def _note_arrival(self, item: TrajectoryItem) -> None:
         self._note_accept(item)
         self._note_frames(item.actor_id - self.slot_base)
+        if self.service is not None:
+            self._note_assembly(item)
 
     # ------------------------------------------------------------------
     # param server: version-gated pub/sub over pipes
@@ -358,6 +360,8 @@ class SocketActorPool(PoolAccounting):
     def _note_arrival(self, item: TrajectoryItem) -> None:
         self._note_accept(item)
         self._note_frames(item.actor_id - self.slot_base)
+        if self.service is not None:
+            self._note_assembly(item)
 
     # ------------------------------------------------------------------
 

@@ -35,6 +35,7 @@ slots are sharded.
 """
 from __future__ import annotations
 
+import functools
 import time
 import traceback
 from typing import Any, Callable, List, Optional, Tuple
@@ -117,17 +118,27 @@ def assemble_inference_traj(steps: List[dict], boot: dict,
     Shared by the thread-mode driver and the process actor loop so the
     layout cannot drift between backends.
 
-    Leaves may be numpy or (possibly still-lazy) device arrays: host
-    stacking forces/views them — ~20x cheaper than the equivalent chain
-    of tiny XLA stack/concat dispatches, and on CPU the conversions are
-    views by the time the unroll ends. Every emitted leaf is numpy, so
-    learner-side stacking takes the staged-buffer path whichever
-    transport carries the item.
+    Where the env-step outputs (frames, rewards, dones) are device
+    arrays, as in the thread driver, one jitted program stacks them
+    there (``_device_stacker``), so the trajectory stays on the
+    learner's device from env step to train step: on a TPU, forcing
+    them to the host costs one transfer per leaf and step (about 300
+    per trajectory at unroll 100), and the learner would copy the
+    frames straight back. Where they are numpy (serialized actors
+    convert each step's outputs, since their trajectories cross a
+    wire), every leaf is stacked on the host. The small reply-side
+    leaves (actions, log-probs, last actions, LSTM state) are host
+    stacks on both paths; the two paths give the same keys, dtypes
+    and values.
 
     ``steps[t]`` keys: obs_image/last_action/last_reward/done_in (the
     step's *inputs*), action/reward/done/behaviour_logprob (its
     outputs). ``boot``: the post-final-step obs_image/last_action/
-    last_reward/done."""
+    last_reward/done. The acting loops carry each step's reward and
+    done into the next step's last_reward/done_in, and the device path
+    relies on that: it takes those two columns from the reward and done
+    stacks, shifted by one step behind the unroll's carried-in input."""
+    import jax
     import numpy as np
 
     def col(k):
@@ -137,22 +148,63 @@ def assemble_inference_traj(steps: List[dict], boot: dict,
         return np.concatenate([col(k), np.asarray(final)[:, None]],
                               axis=1)
 
-    step_dones = col("done")
+    if isinstance(steps[0]["obs_image"], jax.Array):
+        env = _device_stacker(icfg.discount)(
+            [s["obs_image"] for s in steps] + [boot["obs_image"]],
+            [s["reward"] for s in steps], [s["done"] for s in steps],
+            steps[0]["last_reward"], steps[0]["done_in"])
+    else:
+        step_dones = col("done")
+        env = {
+            "rewards": col("reward"),
+            "discounts": (icfg.discount *
+                          (1.0 - step_dones.astype(np.float32))
+                          ).astype(np.float32),
+            "done": step_dones,
+            "obs_image": col_boot("obs_image", boot["obs_image"]),
+            "last_reward": col_boot("last_reward", boot["last_reward"]),
+            "done_in": col_boot("done_in", boot["done"]),
+        }
     return {
         "actions": col("action"),
-        "rewards": col("reward"),
-        "discounts": (icfg.discount *
-                      (1.0 - step_dones.astype(np.float32))
-                      ).astype(np.float32),
+        "rewards": env["rewards"],
+        "discounts": env["discounts"],
         "behaviour_logprob": col("behaviour_logprob"),
-        "done": step_dones,
-        "obs_image": col_boot("obs_image", boot["obs_image"]),
+        "done": env["done"],
+        "obs_image": env["obs_image"],
         "last_action": col_boot("last_action", boot["last_action"]),
-        "last_reward": col_boot("last_reward", boot["last_reward"]),
-        "done_in": col_boot("done_in", boot["done"]),
+        "last_reward": env["last_reward"],
+        "done_in": env["done_in"],
         "lstm_state": (np.asarray(init_lstm[0]),
                        np.asarray(init_lstm[1])),
     }
+
+
+@functools.lru_cache(maxsize=None)
+def _device_stacker(discount: float):
+    """The device half of ``assemble_inference_traj``: one program per
+    (envs, unroll) shape stacks an unroll's frames (bootstrap frame
+    last), rewards and dones batch-major, and derives the discounts and
+    the shifted last_reward/done_in columns from the same stacks."""
+    import jax
+    import jax.numpy as jnp
+
+    @jax.jit
+    def stack(frames, rewards, dones, last_reward0, done_in0):
+        rewards = jnp.stack(rewards, axis=1)
+        dones = jnp.stack(dones, axis=1)
+        return {
+            "rewards": rewards,
+            "discounts": (discount * (1.0 - dones.astype(jnp.float32))
+                          ).astype(jnp.float32),
+            "done": dones,
+            "obs_image": jnp.stack(frames, axis=1),
+            "last_reward": jnp.concatenate(
+                [last_reward0[:, None], rewards], axis=1),
+            "done_in": jnp.concatenate([done_in0[:, None], dones], axis=1),
+        }
+
+    return stack
 
 
 class _ActingState:
@@ -277,9 +329,10 @@ def run_inference_actor_loop(
     blocks (transport backpressure): the service stops counting paused
     clients towards its all-clients-ready flush rule, so a
     learner-throttled actor never holds the others' batches hostage to
-    the flush deadline. Short gaps (trajectory assembly, ~0.5ms)
-    deliberately do NOT pause: fracturing the bucket costs more than
-    the others waiting out a sub-millisecond straggler.
+    the flush deadline. Short gaps (trajectory assembly, numpy stacks
+    of the unroll's steps here) deliberately do NOT pause: fracturing
+    the bucket costs more than the others waiting out a brief
+    straggler.
 
     The trajectory emitted recombines the streams along the batch axis
     and is bit-compatible with the unroll actor's layout
@@ -389,9 +442,10 @@ def run_inference_driver_loop(
     serialize anyway, paying an Event wake-up per actor per step on the
     critical path. This driver multiplexes the logical actors instead:
     submit every actor's per-step request, execute the flush inline
-    (``service.drive_flushes``), dispatch every env step (lazily — the
-    results are only forced by the next flush or the unroll assembly),
-    repeat. A full acting cycle has zero cross-thread handoffs.
+    (``service.drive_flushes``), dispatch every env step (its results
+    stay on the device, where the next flush and the unroll assembly
+    consume them), repeat. A full acting cycle has zero cross-thread
+    handoffs.
 
     Each logical actor keeps exactly the identity it has under the
     per-thread layout: its own env batch, its own
@@ -417,9 +471,9 @@ def run_inference_driver_loop(
 
     t_len = icfg.unroll_length
     reset_batch, step_batch = _make_inference_env_fns(env, num_envs)
-    # identity conv: env-step outputs stay lazy device values — the
-    # next flush (or the unroll assembly) forces them off this thread's
-    # critical path. Replies are already numpy (materialized once,
+    # identity conv: env-step outputs stay device values — the next
+    # flush concatenates them on the device, and the unroll assembly
+    # stacks them there. Replies are already numpy (materialized once,
     # service-side).
     conv = (lambda x: x)
 
@@ -458,9 +512,9 @@ def run_inference_driver_loop(
                                                conv)
 
         for a in actors:
-            # env-step leaves recorded above may still be lazy device
-            # values: assemble_inference_traj forces them (free views
-            # by now — the flushes consumed their upstream chains)
+            # the env-step leaves recorded above are device arrays:
+            # assemble_inference_traj stacks them in one device program,
+            # and the trajectory reaches the learner without a host trip
             with span("acting.assemble"):
                 traj = assemble_inference_traj(a.steps, _acting_boot(a),
                                                init_lstm[a.uid], icfg)

@@ -50,7 +50,8 @@ put down to the host work that filled it. The names
                       env-step dispatch
     acting.env_step   nested in acting.step: the replies taken and the
                       env steps dispatched
-    acting.assemble   one inference-mode trajectory packed on the host
+    acting.assemble   one inference-mode trajectory packed (the thread
+                      driver's is one device program's dispatch)
     acting.emit       one trajectory handed to the transport, with its
                       backpressure retries (driver and unroll actors)
     acting.unroll     one unroll actor's jitted unroll, to

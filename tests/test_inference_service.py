@@ -185,6 +185,10 @@ def test_process_inference_actors_train_and_close_cleanly():
     assert np.isfinite(float(metrics["loss/total"]))
     assert tel["actors"]["backend"] == "process"
     assert tel["queue"]["wire_received"] >= 6
+    # the children's leaves are numpy: every trajectory host-assembled
+    assert tel["actors"]["assembled_on_device"] == 0
+    assert tel["actors"]["assembled_on_host"] == \
+        tel["actors"]["trajectories"] >= 6
     assert tel["inference"]["flushes"] > 0
     assert tel["lag"]["measured"] >= 6
     # clean shutdown: no orphaned actor process may outlive the run
