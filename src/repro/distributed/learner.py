@@ -339,7 +339,12 @@ class _HostStager:
 
 
 def _stack(items: List[TrajectoryItem],
-           stager: Optional[_HostStager] = None) -> PyTree:
+           stager: Optional[_HostStager] = None,
+           routes: Optional[Dict[str, Any]] = None) -> PyTree:
+    """The items' trajectories as one batch. ``routes`` (SPMD learner)
+    counts how it reached the mesh: ``sharded``, staged from host
+    buffers by the stager; ``resharded``, device leaves moved by
+    ``_HostStager.reshard``."""
     import jax
     import jax.numpy as jnp
 
@@ -351,6 +356,8 @@ def _stack(items: List[TrajectoryItem],
     if stager is not None:
         staged = stager.stack(items)
         if staged is not None:
+            if routes is not None:
+                routes["sharded"].inc()
             return staged
 
     def cat(*xs):
@@ -360,9 +367,15 @@ def _stack(items: List[TrajectoryItem],
             return np.concatenate(xs, axis=0)
         return jnp.concatenate(xs, axis=0)
 
-    out = jax.tree.map(cat, *[it.data for it in items])
-    if stager is not None and stager._mesh is not None:
-        out = stager.reshard(out)
+    if stager is None or stager._mesh is None:
+        return jax.tree.map(cat, *[it.data for it in items])
+    # SPMD, leaves the stager could not take (device arrays from thread
+    # actors, ragged trees): concatenated where they live, then moved
+    # onto the mesh
+    with span("learner.reshard"):
+        out = stager.reshard(jax.tree.map(cat, *[it.data for it in items]))
+    if routes is not None:
+        routes["resharded"].inc()
     return out
 
 
@@ -627,6 +640,14 @@ class Learner:
                            "device_put": 0.0, "step": 0.0, "publish": 0.0}
         self._phase_n = 0
         reg = self.obs_registry
+        # SPMD only: how each update's batch reached the mesh
+        # (``sharded`` + ``resharded`` = batches, counted by ``_stack``),
+        # and apart from those, how often the batch-replicated fallback
+        # step ran (every device computing the whole batch)
+        self._spmd_batches = (
+            {k: reg.counter(f"learner.spmd_batches.{k}")
+             for k in ("sharded", "resharded", "replicated")}
+            if spmd_on else None)
         reg.register_producer("learner", self._core_telemetry)
         reg.register_producer(
             "queue", lambda: (self.queue.snapshot()
@@ -759,6 +780,8 @@ class Learner:
                     "spmd_devices": ex.get(
                         "devices", int(self._spmd_mesh.devices.size)),
                     "rounds": ex.get("rounds", 0),
+                    "batches": {k: c.value for k, c in
+                                self._spmd_batches.items()},
                 }
         if "supervisor" in col:
             # supervised only: restart/failover/lease-reap counts ride
@@ -921,6 +944,8 @@ class Learner:
             # version number and books the round.
             t0 = time.monotonic()
             step_fn = self._spmd_step_for(batch)
+            if step_fn is self._train_step_repl:
+                self._spmd_batches["replicated"].inc()
             if self._replay is not None:
                 self._params, self._opt_state, metrics = step_fn(
                     self._params, self._target_params, self._opt_state,
@@ -1103,7 +1128,8 @@ class Learner:
                                    if samples else items)
                     if want_t:
                         self._stager.last_device_put_s = 0.0
-                    batch = _stack(train_items, self._stager)
+                    batch = _stack(train_items, self._stager,
+                                   self._spmd_batches)
                     if self._replay is not None:
                         # replayed rows sit FIRST in the stacked batch;
                         # the mask rides as data so every bucket keeps a

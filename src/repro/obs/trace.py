@@ -64,6 +64,10 @@ put down to the host work that filled it. The names
                       collect, bookkeeping, replay, stack and its
                       ``device_put`` (a lone trajectory goes to the
                       device inside the step's call)
+    learner.reshard   nested in learner.stage (SPMD learner): a batch
+                      the host stager could not take (device arrays
+                      from thread actors) concatenated where it lives
+                      and moved onto the ``('data',)`` mesh (dispatch)
     learner.step      the update (on the fused path, its dispatch)
     learner.publish   the parameter publish
 
@@ -95,8 +99,8 @@ CLOCK_SKEW_S = 5.0
 
 HOST_SPAN_NAMES = ("acting.step", "acting.env_step", "acting.assemble",
                    "acting.emit", "acting.unroll", "infer.flush",
-                   "learner.wait", "learner.stage", "learner.step",
-                   "learner.publish")
+                   "learner.wait", "learner.stage", "learner.reshard",
+                   "learner.step", "learner.publish")
 _HOST_SPANS = frozenset(HOST_SPAN_NAMES)
 
 
