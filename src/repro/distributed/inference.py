@@ -20,7 +20,10 @@ flushes on whichever comes first:
 Two client frontends share the service core:
 
   thread    ``service.connect()`` — requests are live array pytrees on a
-            lock-protected deque, replies delivered through an Event.
+            lock-protected deque, replies delivered through an Event. A
+            request of device arrays gets its reply as device arrays,
+            cut from the flush inside its program: the thread driver's
+            acting steps never wait for a flush or copy its results.
   process   ``service.process_frontend(ctx)`` — requests travel as
             serde-encoded frames over a bounded multiprocessing wire,
             replies go back serde-encoded over a per-client pipe (the
@@ -59,7 +62,8 @@ _STOP_FRAME = b""          # reply-pipe sentinel: service shut down
 
 
 class InferenceReply(NamedTuple):
-    """One client's slice of a flushed batch."""
+    """One client's slice of a flushed batch: device arrays for a
+    request of device arrays, numpy otherwise."""
     action: Any                # (B,) int32
     logprob: Any               # (B,) f32 — behaviour log pi(a|x)
     lstm_state: Tuple[Any, Any]  # ((B, W), (B, W)) next recurrent state
@@ -202,6 +206,8 @@ class InferenceService:
         self.flush_ready = 0
         self.flush_timeouts = 0
         self.padded_requests = 0
+        self.device_replies = 0     # requests answered with device rows
+        self.host_replies = 0       # ... with numpy rows
         self._last_version = -1
 
         self._thread = threading.Thread(target=self._loop,
@@ -228,10 +234,14 @@ class InferenceService:
     # the jitted flush: concat K requests -> one forward -> sample
 
     def _build_flush(self, k: int) -> Callable:
+        """The batched forward of a ``k``-request bucket: concatenate the
+        requests, one forward, sample. Returns the concatenated
+        (action, logp, h, c); traced inside the bucket's program
+        (``_bucket_program``)."""
         arch, num_actions = self._arch, self._num_actions
         base_key = self._key
 
-        def flush(params, seq, reqs):
+        def forward(params, seq, reqs):
             # per-flush RNG stream derived *inside* the jit: a host-side
             # split/fold would cost one more device dispatch per flush
             key = jax.random.fold_in(base_key, seq)
@@ -254,6 +264,21 @@ class InferenceService:
             h, c = out.cache
             return action, logp, h, c
 
+        return forward
+
+    def _bucket_program(self, k: int) -> Callable:
+        """The jitted flush of a ``k``-request bucket. It returns the
+        concatenated (action, logp, h, c), which a numpy client's reply
+        slices on the host, and each request's own four rows, cut inside
+        the program, which a device client's reply is handed as is."""
+        forward = self._build_flush(k)
+
+        def flush(params, seq, reqs):
+            out = forward(params, seq, reqs)
+            cuts = np.cumsum([r["last_action"].shape[0]
+                              for r in reqs])[:-1].tolist()
+            return out, tuple(zip(*(jnp.split(x, cuts) for x in out)))
+
         return jax.jit(flush)
 
     def _warm_buckets(self, sample: PyTree) -> None:
@@ -272,7 +297,7 @@ class InferenceService:
                 with self._lock:
                     fn = self._flush_fns.get(b)
                     if fn is None:
-                        fn = self._flush_fns[b] = self._build_flush(b)
+                        fn = self._flush_fns[b] = self._bucket_program(b)
                 jax.block_until_ready(fn(params, np.int64(0),
                                          (sample,) * b))
                 b *= 2
@@ -344,12 +369,19 @@ class InferenceService:
             params, version = self._store.pull()
             now = time.monotonic()
             reqs = [p.data for p in batch] + [batch[-1].data] * (kb - k)
-            # materialize ONCE: the flush must complete before any reply is
-            # usable, and numpy row slices are free views — handing out lazy
-            # device slices instead makes every client pay its own forced
-            # execution (~1ms each, measured) on its critical path
-            action, logp, h, c = (np.asarray(x) for x in
-                                  fn(params, np.int64(seq), tuple(reqs)))
+            out, parts = fn(params, np.int64(seq), tuple(reqs))
+            # a request of device arrays (the thread driver's) is answered
+            # with its own rows as the program cut them: they stay on the
+            # device, and nothing waits for the flush. A numpy request
+            # (process and remote clients) needs host arrays: materialize
+            # the concatenated outputs ONCE, since numpy row slices are
+            # free views, where a lazy device slice per client would cost
+            # each its own forced execution (~1ms each, measured)
+            on_device = [all(isinstance(x, jax.Array)
+                             for x in jax.tree.leaves(p.data))
+                         for p in batch]
+            host = (None if all(on_device) else
+                    [np.asarray(x) for x in out])
 
             with self._lock:        # snapshot() reads these concurrently
                 self.batch_hist[k] += 1
@@ -360,20 +392,21 @@ class InferenceService:
                 else:
                     self.flush_timeouts += 1
                 self._c_requests.inc(k)
+                self.device_replies += sum(on_device)
+                self.host_replies += k - sum(on_device)
                 self.padded_requests += kb - k
                 self._last_version = version
                 for p in batch:
                     self._c_frames.inc(p.data["last_action"].shape[0])
                     self.wait_hist[_wait_bucket(now - p.submitted_at)] += 1
             off = 0
-            for p in batch:
+            for p, dev, part in zip(batch, on_device, parts):
                 b = p.data["last_action"].shape[0]
-                reply = InferenceReply(action[off:off + b], logp[off:off + b],
-                                       (h[off:off + b], c[off:off + b]),
-                                       version)
+                action, logp, h, c = (part if dev else
+                                      (x[off:off + b] for x in host))
                 off += b
                 try:
-                    p.reply_fn(reply)
+                    p.reply_fn(InferenceReply(action, logp, (h, c), version))
                 except Exception as e:      # a dead pipe must not kill a flush
                     self.errors.append(e)
 
@@ -574,6 +607,8 @@ class InferenceService:
                 "batch_size_hist": dict(sorted(self.batch_hist.items())),
                 "requests": self.requests,
                 "padded_requests": self.padded_requests,
+                "device_replies": self.device_replies,
+                "host_replies": self.host_replies,
                 "frames": self.frames,
                 "mean_batch": (self.requests / flushes if flushes else 0.0),
                 # bucket k covers [2^(k-1), 2^k) µs; /metrics renders

@@ -109,6 +109,11 @@ def run_actor_loop(
             break
 
 
+_TRAJ_KEYS = ("actions", "rewards", "discounts", "behaviour_logprob",
+              "done", "obs_image", "last_action", "last_reward", "done_in",
+              "lstm_state")
+
+
 def assemble_inference_traj(steps: List[dict], boot: dict,
                             init_lstm: Tuple[Any, Any], icfg) -> dict:
     """Package one unroll's per-step records into the learner's
@@ -119,25 +124,25 @@ def assemble_inference_traj(steps: List[dict], boot: dict,
     layout cannot drift between backends.
 
     Where the env-step outputs (frames, rewards, dones) are device
-    arrays, as in the thread driver, one jitted program stacks them
-    there (``_device_stacker``), so the trajectory stays on the
-    learner's device from env step to train step: on a TPU, forcing
-    them to the host costs one transfer per leaf and step (about 300
-    per trajectory at unroll 100), and the learner would copy the
-    frames straight back. Where they are numpy (serialized actors
-    convert each step's outputs, since their trajectories cross a
-    wire), every leaf is stacked on the host. The small reply-side
-    leaves (actions, log-probs, last actions, LSTM state) are host
-    stacks on both paths; the two paths give the same keys, dtypes
-    and values.
+    arrays, as in the thread driver, one jitted program stacks every
+    column there (``_device_stacker``), the reply-side ones (actions,
+    log-probs, last actions, the initial LSTM state) too, so the
+    trajectory stays on the learner's device from env step to train
+    step: on a TPU, forcing them to the host costs one transfer per
+    leaf and step (five a step, about 500 per trajectory at unroll
+    100), and the learner would copy them straight back. Where they are numpy
+    (serialized actors convert each step's outputs, since their
+    trajectories cross a wire), every leaf is stacked on the host. The
+    two paths give the same keys, dtypes and values.
 
     ``steps[t]`` keys: obs_image/last_action/last_reward/done_in (the
     step's *inputs*), action/reward/done/behaviour_logprob (its
     outputs). ``boot``: the post-final-step obs_image/last_action/
-    last_reward/done. The acting loops carry each step's reward and
-    done into the next step's last_reward/done_in, and the device path
-    relies on that: it takes those two columns from the reward and done
-    stacks, shifted by one step behind the unroll's carried-in input."""
+    last_reward/done. The acting loops carry each step's action, reward
+    and done into the next step's last_action/last_reward/done_in, and
+    the device path relies on that: it takes those three columns from
+    the action, reward and done stacks, shifted by one step behind the
+    unroll's carried-in input."""
     import jax
     import numpy as np
 
@@ -149,59 +154,67 @@ def assemble_inference_traj(steps: List[dict], boot: dict,
                               axis=1)
 
     if isinstance(steps[0]["obs_image"], jax.Array):
-        env = _device_stacker(icfg.discount)(
+        cols = _device_stacker(icfg.discount)(
             [s["obs_image"] for s in steps] + [boot["obs_image"]],
             [s["reward"] for s in steps], [s["done"] for s in steps],
-            steps[0]["last_reward"], steps[0]["done_in"])
+            [s["action"] for s in steps],
+            [s["behaviour_logprob"] for s in steps],
+            {k: steps[0][k] for k in ("last_action", "last_reward",
+                                      "done_in")},
+            tuple(init_lstm))
     else:
         step_dones = col("done")
-        env = {
+        cols = {
+            "actions": col("action"),
             "rewards": col("reward"),
             "discounts": (icfg.discount *
                           (1.0 - step_dones.astype(np.float32))
                           ).astype(np.float32),
+            "behaviour_logprob": col("behaviour_logprob"),
             "done": step_dones,
             "obs_image": col_boot("obs_image", boot["obs_image"]),
+            "last_action": col_boot("last_action", boot["last_action"]),
             "last_reward": col_boot("last_reward", boot["last_reward"]),
             "done_in": col_boot("done_in", boot["done"]),
+            "lstm_state": (np.asarray(init_lstm[0]),
+                           np.asarray(init_lstm[1])),
         }
-    return {
-        "actions": col("action"),
-        "rewards": env["rewards"],
-        "discounts": env["discounts"],
-        "behaviour_logprob": col("behaviour_logprob"),
-        "done": env["done"],
-        "obs_image": env["obs_image"],
-        "last_action": col_boot("last_action", boot["last_action"]),
-        "last_reward": env["last_reward"],
-        "done_in": env["done_in"],
-        "lstm_state": (np.asarray(init_lstm[0]),
-                       np.asarray(init_lstm[1])),
-    }
+    # the layout's key order (a jitted program returns its dict sorted)
+    return {k: cols[k] for k in _TRAJ_KEYS}
 
 
 @functools.lru_cache(maxsize=None)
 def _device_stacker(discount: float):
     """The device half of ``assemble_inference_traj``: one program per
     (envs, unroll) shape stacks an unroll's frames (bootstrap frame
-    last), rewards and dones batch-major, and derives the discounts and
-    the shifted last_reward/done_in columns from the same stacks."""
+    last), rewards, dones, actions and log-probs batch-major, derives
+    the discounts and the shifted last_action/last_reward/done_in
+    columns from the same stacks, and passes the initial LSTM state
+    through."""
     import jax
     import jax.numpy as jnp
 
     @jax.jit
-    def stack(frames, rewards, dones, last_reward0, done_in0):
+    def stack(frames, rewards, dones, actions, logprobs, first, lstm):
         rewards = jnp.stack(rewards, axis=1)
         dones = jnp.stack(dones, axis=1)
+        actions = jnp.stack(actions, axis=1)
+
+        def shifted(first, col):
+            return jnp.concatenate([first[:, None], col], axis=1)
+
         return {
+            "actions": actions,
             "rewards": rewards,
             "discounts": (discount * (1.0 - dones.astype(jnp.float32))
                           ).astype(jnp.float32),
+            "behaviour_logprob": jnp.stack(logprobs, axis=1),
             "done": dones,
             "obs_image": jnp.stack(frames, axis=1),
-            "last_reward": jnp.concatenate(
-                [last_reward0[:, None], rewards], axis=1),
-            "done_in": jnp.concatenate([done_in0[:, None], dones], axis=1),
+            "last_action": shifted(first["last_action"], actions),
+            "last_reward": shifted(first["last_reward"], rewards),
+            "done_in": shifted(first["done_in"], dones),
+            "lstm_state": lstm,
         }
 
     return stack
@@ -213,7 +226,7 @@ class _ActingState:
     on an actor thread's stack."""
 
     __slots__ = ("uid", "client", "state", "obs_image", "last_action",
-                 "last_reward", "done", "h", "c", "key", "ukey",
+                 "last_reward", "done", "h", "c", "key", "ukey", "t",
                  "steps", "version", "handle")
 
 
@@ -230,20 +243,26 @@ def _make_inference_env_fns(env, n: int):
     @jax.jit
     def step_batch(state, action, key, t):
         # fold the step index in here: deriving per-step keys outside
-        # would cost one extra device op on every step's critical path
+        # would cost one extra device op on every step's critical path.
+        # The index comes back incremented, so it stays on the device
+        # from step to step instead of crossing from the host each time
         keys = jax.random.split(jax.random.fold_in(key, t), n)
         state, ts = jax.vmap(env.step)(state, action, keys)
         # only what the service request / trajectory needs: XLA dead-
         # code-eliminates the rest of the TimeStep (e.g. obs_token)
-        return state, (ts.obs_image, ts.reward, ts.done)
+        return state, t + 1, (ts.obs_image, ts.reward, ts.done)
 
     return reset_batch, step_batch
 
 
 def _init_acting_state(uid, base_key, reset_batch, arch_cfg, n: int,
                        conv, client=None) -> _ActingState:
+    """``conv`` places the carried leaves: numpy for the serialized
+    loop, identity for the thread driver, whose every request leaf is
+    then a device array from the first step on (so the service answers
+    it on the device)."""
     import jax
-    import numpy as np
+    import jax.numpy as jnp
 
     from repro.models import lstm as lstm_lib
 
@@ -252,9 +271,9 @@ def _init_acting_state(uid, base_key, reset_batch, arch_cfg, n: int,
     st.client = client
     st.state, ts = reset_batch(jax.random.fold_in(base_key, 1))
     st.obs_image = conv(ts.obs_image)
-    st.last_action = np.zeros((n,), np.int32)
-    st.last_reward = np.zeros((n,), np.float32)
-    st.done = np.zeros((n,), bool)
+    st.last_action = conv(jnp.zeros((n,), jnp.int32))
+    st.last_reward = conv(jnp.zeros((n,), jnp.float32))
+    st.done = conv(jnp.zeros((n,), bool))
     st.h, st.c = (conv(x) for x in
                   lstm_lib.lstm_zero_state(n, arch_cfg.lstm_width))
     st.key = jax.random.fold_in(base_key, 2)
@@ -272,20 +291,31 @@ def _acting_boot(st: _ActingState) -> dict:
             "last_reward": st.last_reward, "done": st.done}
 
 
-def _record_reply_and_step(st: _ActingState, reply, step_batch, t: int,
+def _start_unroll(st: _ActingState, unroll_idx: int) -> None:
+    """Reset the per-unroll carry: the unroll's key, its step index
+    (a device zero, the type ``step_batch`` returns it as, so both hit
+    one compiled program) and its records."""
+    import jax
+    import jax.numpy as jnp
+
+    st.steps = []
+    st.version = None
+    st.ukey = jax.random.fold_in(st.key, unroll_idx)
+    st.t = jnp.zeros((), jnp.int32)
+
+
+def _record_reply_and_step(st: _ActingState, reply, step_batch,
                            conv) -> None:
     """The shared per-step bookkeeping: stamp the first-step version,
     advance the recurrent state from the reply, step the envs, record
     the step, carry forward."""
-    import numpy as np
-
     if st.version is None:
         st.version = reply.param_version
     action = conv(reply.action)
     st.h = conv(reply.lstm_state[0])
     st.c = conv(reply.lstm_state[1])
-    st.state, (obs_image, reward, step_done) = step_batch(
-        st.state, action, st.ukey, np.int32(t))
+    st.state, st.t, (obs_image, reward, step_done) = step_batch(
+        st.state, action, st.ukey, st.t)
     st.steps.append({
         "obs_image": st.obs_image, "last_action": st.last_action,
         "last_reward": st.last_reward, "done_in": st.done,
@@ -382,9 +412,7 @@ def run_inference_actor_loop(
         u0 = time.monotonic() if sampled else 0.0
         init_lstm = [(st.h, st.c) for st in streams]
         for st in streams:
-            st.steps = []
-            st.version = None
-            st.ukey = jax.random.fold_in(st.key, unroll_idx)
+            _start_unroll(st, unroll_idx)
             if n_streams > 1:
                 st.handle = st.client.submit_async(_acting_request(st))
         for t in range(t_len):
@@ -401,7 +429,7 @@ def run_inference_actor_loop(
                     reply = st.client.infer(_acting_request(st))
                 if reply is None:
                     return              # service shut down mid-unroll
-                _record_reply_and_step(st, reply, step_batch, t, conv)
+                _record_reply_and_step(st, reply, step_batch, conv)
                 if n_streams > 1 and t + 1 < t_len:
                     st.handle = st.client.submit_async(_acting_request(st))
 
@@ -471,10 +499,10 @@ def run_inference_driver_loop(
 
     t_len = icfg.unroll_length
     reset_batch, step_batch = _make_inference_env_fns(env, num_envs)
-    # identity conv: env-step outputs stay device values — the next
-    # flush concatenates them on the device, and the unroll assembly
-    # stacks them there. Replies are already numpy (materialized once,
-    # service-side).
+    # identity conv: env-step outputs and replies stay device values —
+    # the next flush concatenates them on the device, the next env step
+    # takes the reply's actions there, and the unroll assembly stacks
+    # them there. No acting step copies to the host or waits for one.
     conv = (lambda x: x)
 
     actors = [
@@ -490,10 +518,8 @@ def run_inference_driver_loop(
         u0 = time.monotonic() if sampled else 0.0
         init_lstm = {a.uid: (a.h, a.c) for a in actors}
         for a in actors:
-            a.steps = []
-            a.version = None
-            a.ukey = jax.random.fold_in(a.key, unroll_idx)
-        for t in range(t_len):
+            _start_unroll(a, unroll_idx)
+        for _ in range(t_len):
             with span("acting.step"):
                 for a in actors:
                     a.handle = service.submit_async(_acting_request(a))
@@ -508,8 +534,7 @@ def run_inference_driver_loop(
                             reply = a.handle.slot[0]
                         if reply is None:
                             return
-                        _record_reply_and_step(a, reply, step_batch, t,
-                                               conv)
+                        _record_reply_and_step(a, reply, step_batch, conv)
 
         for a in actors:
             # the env-step leaves recorded above are device arrays:

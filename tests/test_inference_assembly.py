@@ -2,7 +2,12 @@
 device arrays, ``assemble_inference_traj`` stacks them on the device in
 one program; where they are numpy, on the host. The two must give the
 same tree — keys in order, shapes, dtypes and every bit — and the
-actor pools count which path each trajectory took."""
+actor pools count which path each trajectory took. The thread driver,
+whose replies stay on the device, must act out the trajectories of a
+host round trip per step, and a warm acting cycle of it must copy
+nothing to the host."""
+import contextlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -114,10 +119,10 @@ def test_device_assembly_matches_host_assembly(reply_leaves_on_device):
 
 
 def test_device_assembly_forces_no_env_output_to_host(monkeypatch):
-    """The thread driver's case (env outputs on the device, replies
-    numpy): no device leaf goes through ``np.asarray``."""
-    steps, boot, lstm = _records(11, reply_leaves_on_device=False)
-    lstm = jax.tree.map(np.asarray, lstm)
+    """The thread driver's case (env outputs and replies on the
+    device): no device leaf goes through ``np.asarray``, and every
+    column stays on the device."""
+    steps, boot, lstm = _records(11, reply_leaves_on_device=True)
     converted = []
     real = np.asarray
 
@@ -132,7 +137,7 @@ def test_device_assembly_forces_no_env_output_to_host(monkeypatch):
     np.asarray(steps[0]["reward"])      # the spy does see a conversion
     monkeypatch.undo()
     assert converted == [(B,)]
-    assert isinstance(traj["obs_image"], jax.Array)
+    assert all(isinstance(x, jax.Array) for x in jax.tree.leaves(traj))
 
 
 def test_device_assembled_trajectory_crosses_the_wire_like_a_host_one():
@@ -229,3 +234,184 @@ def test_serialized_inference_loop_emits_numpy():
         leaves = jax.tree.leaves(it.data)
         assert all(isinstance(x, np.ndarray) for x in leaves)
         assert it.data["obs_image"].shape == (4, 6) + env.image_hw
+
+
+# ---------------------------------------------------------------------------
+# the thread driver: replies on the device, the same trajectories as a
+# host round trip per step, and no copy to the host
+
+
+class _HostRoundTrip:
+    """The service as the thread driver met it with a host round trip per
+    step: each request crosses to the host, so each reply comes back
+    numpy, and the next step sends it back."""
+
+    def __init__(self, service):
+        self.service = service
+
+    def submit_async(self, data):
+        return self.service.submit_async(jax.tree.map(np.asarray, data))
+
+    def drive_flushes(self):
+        self.service.drive_flushes()
+
+    def wait(self, w):
+        return self.service.wait(w)
+
+
+def _host_index_env_fns(make):
+    """``_make_inference_env_fns`` with the step index a host integer,
+    passed as ``np.int32`` at every step, as before it stayed on the
+    device."""
+    def fns(env, n):
+        reset_batch, step_batch = make(env, n)
+
+        def step(state, action, key, t):
+            t = int(t)
+            state, _, out = step_batch(state, action, key, np.int32(t))
+            return state, t + 1, out
+        return reset_batch, step
+    return fns
+
+
+def _driver_service(env, arch, icfg, num_actors):
+    from repro.distributed import ParameterStore
+    from repro.distributed.inference import InferenceService
+    from repro.models import backbone as bb
+    from repro.models import common as pcommon
+
+    params = pcommon.init_params(bb.backbone_specs(arch, env.num_actions),
+                                 jax.random.key(0))
+    return InferenceService(env, arch, icfg, ParameterStore(params),
+                            num_clients=num_actors, seed=3)
+
+
+def _drive(service, env, arch, icfg, unrolls, on_emit=None):
+    """Two logical actors, ``unrolls`` unrolls each; the items in emit
+    order."""
+    items = []
+
+    def emit(uid, item):
+        items.append(item)
+        if on_emit is not None:
+            on_emit(items)
+        return True
+
+    runner.run_inference_driver_loop(
+        actor_ids=[0, 1], env=env, arch_cfg=arch, icfg=icfg, num_envs=4,
+        seed=9, service=service, emit=emit,
+        should_stop=lambda: len(items) >= 2 * unrolls)
+    return items
+
+
+@pytest.mark.timeout_s(300)
+def test_driver_trajectories_equal_the_host_round_trip(monkeypatch):
+    """For a fixed seed on catch (episodes end inside unrolls), the
+    driver's trajectories equal key by key — values, dtypes and the
+    initial LSTM state — those of the same driver with a host round
+    trip per step: numpy requests and replies, a host step index, and
+    every column stacked on the host. So the sampled actions and
+    log-probs are those the round trip gave."""
+    env = make_catch()
+    arch = small_arch(env)
+    icfg = _icfg(num_actions=env.num_actions, unroll_length=5)
+
+    dev_svc = _driver_service(env, arch, icfg, 2)
+    try:
+        dev = _drive(dev_svc, env, arch, icfg, unrolls=3)
+    finally:
+        dev_svc.stop()
+
+    real = runner.assemble_inference_traj
+
+    def on_host(steps, boot, init_lstm, icfg):
+        return real(jax.tree.map(np.asarray, steps),
+                    jax.tree.map(np.asarray, boot),
+                    jax.tree.map(np.asarray, init_lstm), icfg)
+
+    monkeypatch.setattr(runner, "assemble_inference_traj", on_host)
+    monkeypatch.setattr(runner, "_make_inference_env_fns",
+                        _host_index_env_fns(runner._make_inference_env_fns))
+    host_svc = _driver_service(env, arch, icfg, 2)
+    try:
+        host = _drive(_HostRoundTrip(host_svc), env, arch, icfg, unrolls=3)
+    finally:
+        host_svc.stop()
+
+    assert len(dev) == len(host) == 6
+    for d, h in zip(dev, host):
+        assert (d.actor_id, d.param_version) == (h.actor_id, h.param_version)
+        assert all(isinstance(x, jax.Array) for x in jax.tree.leaves(d.data))
+        assert all(isinstance(x, np.ndarray)
+                   for x in jax.tree.leaves(h.data))
+        _assert_bit_identical(d.data, h.data)
+    assert any(np.asarray(h.data["done"]).any() for h in host)
+    # the LSTM state carried across unrolls is not the zero state
+    assert np.asarray(dev[-1].data["lstm_state"][0]).any()
+    dev_snap, host_snap = dev_svc.snapshot(), host_svc.snapshot()
+    requests = 3 * 2 * icfg.unroll_length
+    assert dev_snap["requests"] == host_snap["requests"] == requests
+    assert (dev_snap["device_replies"], dev_snap["host_replies"]) == \
+        (requests, 0)
+    assert (host_snap["device_replies"], host_snap["host_replies"]) == \
+        (0, requests)
+
+
+@pytest.mark.timeout_s(300)
+def test_warm_driver_cycle_copies_nothing_to_the_host(monkeypatch):
+    """After one acting cycle to warm up, a second runs under
+    ``jax.transfer_guard_device_to_host("disallow")`` and completes.
+    The guard catches copies on an accelerator; on the CPU a device
+    array is already host memory and the guard lets every fetch pass,
+    so spies on the two ways out count them too: the buffer protocol
+    (``np.asarray``, ``np.stack``) and ``ArrayImpl._value`` (``int``,
+    ``tolist``, ``device_get``, ``__array__``)."""
+    from jax._src.array import ArrayImpl
+
+    env = make_catch()
+    arch = small_arch(env)
+    icfg = _icfg(num_actions=env.num_actions, unroll_length=5)
+    fetched = []
+    armed = [False]
+    real_value, real_buffer = ArrayImpl._value, ArrayImpl.__buffer__
+
+    def value(self):
+        if armed[0]:
+            fetched.append(("value", self.shape))
+        return real_value.fget(self)
+
+    def buffer(self, flags):
+        if armed[0]:
+            fetched.append(("buffer", self.shape))
+        return real_buffer(self, flags)
+
+    monkeypatch.setattr(ArrayImpl, "_value", property(value))
+    monkeypatch.setattr(ArrayImpl, "__buffer__", buffer)
+    guard = contextlib.ExitStack()
+
+    def on_emit(items):
+        if len(items) == 2:         # the warm cycle has emitted
+            guard.enter_context(
+                jax.transfer_guard_device_to_host("disallow"))
+            armed[0] = True
+
+    svc = _driver_service(env, arch, icfg, 2)
+    try:
+        with guard:
+            items = _drive(svc, env, arch, icfg, unrolls=2,
+                           on_emit=on_emit)
+            armed[0] = False
+    finally:
+        svc.stop()
+    assert len(items) == 4
+    assert fetched == [], fetched
+    snap = svc.snapshot()
+    assert snap["host_replies"] == 0
+    assert snap["device_replies"] == snap["requests"] == \
+        2 * 2 * icfg.unroll_length
+    # the spies do see a fetch
+    armed[0] = True
+    actions = items[-1].data["actions"]
+    np.asarray(actions)
+    int(actions[0, 0])
+    assert fetched == [("buffer", (4, icfg.unroll_length)), ("value", ())]

@@ -142,6 +142,112 @@ def test_service_stop_unblocks_clients():
 
 
 # ---------------------------------------------------------------------------
+# where a reply lives: device rows for a request of device arrays, numpy
+# rows for a numpy request, the same numbers either way
+
+
+def _random_request(rng, n, width, hw):
+    return {
+        "obs_image": rng.integers(0, 256, (n,) + hw).astype(np.uint8),
+        "last_action": rng.integers(0, 3, n).astype(np.int32),
+        "last_reward": rng.standard_normal(n).astype(np.float32),
+        "done": rng.random(n) < 0.3,
+        "lstm_h": rng.standard_normal((n, width)).astype(np.float32),
+        "lstm_c": rng.standard_normal((n, width)).astype(np.float32),
+    }
+
+
+def _flush_once(svc, reqs):
+    """Submit ``reqs`` and flush them all in one program, as the thread
+    driver does; the replies in submission order."""
+    waiters = [svc.submit_async(r) for r in reqs]
+    svc.drive_flushes()
+    assert all(w.event.is_set() for w in waiters)
+    return [w.slot[0] for w in waiters]
+
+
+def _reply_leaves(r):
+    return [r.action, r.logprob, r.lstm_state[0], r.lstm_state[1]]
+
+
+def _assert_same_reply(got, want):
+    assert got.param_version == want.param_version
+    for x, y in zip(_reply_leaves(got), _reply_leaves(want)):
+        x, y = np.asarray(x), np.asarray(y)
+        assert (x.shape, x.dtype) == (y.shape, y.dtype)
+        assert x.tobytes() == y.tobytes()
+
+
+@pytest.mark.timeout_s(180)
+@pytest.mark.parametrize("k", [1, 2, 8, 3],
+                         ids=["bucket1", "bucket2", "bucket8", "padded3of4"])
+def test_device_requests_get_device_replies_equal_to_numpy_replies(k):
+    """The same requests, once as numpy and once as device arrays, to
+    two services with the same seed and params, each in its first
+    flush (so the same ``seq``): the device replies are device arrays,
+    the numpy replies numpy, and they agree bit for bit."""
+    import jax
+
+    host_svc, arch, n = _make_service(num_clients=8)
+    dev_svc, _, _ = _make_service(num_clients=8)
+    rng = np.random.default_rng(k)
+    reqs = [_random_request(rng, n, arch.lstm_width, make_bandit().image_hw)
+            for _ in range(k)]
+    try:
+        host = _flush_once(host_svc, reqs)
+        dev = _flush_once(dev_svc, [jax.device_put(r) for r in reqs])
+    finally:
+        host_svc.stop()
+        dev_svc.stop()
+    for h, d in zip(host, dev):
+        assert all(isinstance(x, np.ndarray) for x in _reply_leaves(h))
+        assert all(isinstance(x, jax.Array) for x in _reply_leaves(d))
+        assert np.asarray(d.action).shape == (n,)
+        assert np.asarray(d.lstm_state[1]).shape == (n, arch.lstm_width)
+        _assert_same_reply(d, h)
+    # every request its own rows: no two clients were handed the same
+    assert len({np.asarray(d.lstm_state[0]).tobytes() for d in dev}) == k
+    bucket = 4 if k == 3 else k
+    for svc, device_replies in ((host_svc, 0), (dev_svc, k)):
+        snap = svc.snapshot()
+        assert snap["device_replies"] == device_replies
+        assert snap["host_replies"] == k - device_replies
+        assert snap["device_replies"] + snap["host_replies"] == \
+            snap["requests"] == k
+        assert snap["padded_requests"] == bucket - k
+        assert snap["batch_size_hist"] == {k: 1}
+
+
+@pytest.mark.timeout_s(180)
+def test_mixed_flush_gives_each_client_its_own_kind_of_reply():
+    """One flush holding numpy and device requests: each client gets
+    its reply where its request lived, with the numbers an all-numpy
+    flush gives, and the counters split the requests between them."""
+    import jax
+
+    mixed_svc, arch, n = _make_service(num_clients=8)
+    host_svc, _, _ = _make_service(num_clients=8)
+    rng = np.random.default_rng(5)
+    reqs = [_random_request(rng, n, arch.lstm_width, make_bandit().image_hw)
+            for _ in range(4)]
+    on_device = [False, True, True, False]
+    try:
+        mixed = _flush_once(mixed_svc, [jax.device_put(r) if d else r
+                                        for r, d in zip(reqs, on_device)])
+        host = _flush_once(host_svc, reqs)
+    finally:
+        mixed_svc.stop()
+        host_svc.stop()
+    for m, h, d in zip(mixed, host, on_device):
+        kind = jax.Array if d else np.ndarray
+        assert all(isinstance(x, kind) for x in _reply_leaves(m)), d
+        _assert_same_reply(m, h)
+    snap = mixed_svc.snapshot()
+    assert (snap["device_replies"], snap["host_replies"]) == (2, 2)
+    assert snap["requests"] == 4
+
+
+# ---------------------------------------------------------------------------
 # end to end through the runtime, both backends
 
 
