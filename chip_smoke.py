@@ -312,8 +312,8 @@ def four_chips(args, clock, tmp):
     params = jax.device_get(s.params)
     n = jax.tree.leaves(host_batch)[0].shape[0]
     with jax.default_matmul_precision("highest"):
-        spmd_step, opt = learner_lib.build_spmd_train_step(
-            arch, icfg, env.num_actions, mesh, vtrace_impl="fused")
+        spmd_step, opt = learner_lib.build_train_step(
+            arch, icfg, env.num_actions, vtrace_impl="fused", mesh=mesh)
         repl = NamedSharding(mesh, P())
         p_spmd, _, _ = jax.jit(spmd_step)(
             jax.device_put(params, repl),

@@ -1,25 +1,32 @@
 """The IMPALA learner: batched V-trace actor-critic updates (paper §3, §4.2).
 
-``build_train_step`` closes over the architecture + IMPALA configs and the
-optimizer and returns a pure ``train_step(params, opt_state, step, batch)``
-suitable for ``jax.jit`` with pjit shardings (see ``repro.launch``). The
-same builder serves the CPU examples and the 512-device dry-run.
+One update is the loss's gradient (``_grad_fn``), an optional mean over
+the learners, then the learning rate, clip, optimizer and apply
+(``_apply_fn``). ``build_train_step`` composes the halves into one
+program, on one device or over a ``('data',)`` mesh with an in-XLA
+``lax.pmean``; ``build_grad_apply_steps`` leaves a host exchange between.
 """
 from __future__ import annotations
 
-import functools
 from typing import Any, Callable, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ArchConfig, ImpalaConfig
+from repro.core import corrections
 from repro.core import losses as losses_lib
 from repro.models import backbone as bb
 from repro.models.common import cast as common_cast
 from repro.optim import optimizer as opt_lib
+from repro.sharding.compat import shard_map
+from repro.sharding.rules import Rules
 
 PyTree = Any
+
+# metrics with one value per batch row, kept out of the mesh's mean
+_PER_ROW_METRICS = ("vtrace/traj_adv_mag",)
 
 
 def forward_trajectory(params, batch: Dict, arch_cfg: ArchConfig,
@@ -47,7 +54,14 @@ def forward_trajectory(params, batch: Dict, arch_cfg: ArchConfig,
 def build_loss_fn(arch_cfg: ArchConfig, cfg: ImpalaConfig,
                   num_actions: int, vtrace_impl: str = "auto",
                   aux_coef: float = 0.01):
-    def loss_fn(params, batch):
+    """``loss_fn(params, batch, target_params=None) -> (total, metrics)``.
+
+    With ``target_params``, the replay loss (IMPACT): rows that
+    ``batch['replay_mask']`` (B,) flags take the target network's values
+    as the V-trace baseline (``corrections.replay_baseline_mix``), online
+    rows the standard loss, and the per-trajectory |pg advantage|
+    ``vtrace/traj_adv_mag`` (B,) comes back as the replay priority."""
+    def loss_fn(params, batch, target_params=None):
         logits, values, aux = forward_trajectory(params, batch, arch_cfg,
                                                  num_actions)
         loss_batch = {
@@ -57,9 +71,20 @@ def build_loss_fn(arch_cfg: ArchConfig, cfg: ImpalaConfig,
             "behaviour_logprob": batch["behaviour_logprob"],
             "bootstrap_value": values[:, -1],
         }
+        replay = {}
+        if target_params is not None:
+            _, tvalues, _ = forward_trajectory(target_params, batch,
+                                               arch_cfg, num_actions)
+            mask = batch["replay_mask"]
+            replay = {
+                "corr_values": corrections.replay_baseline_mix(
+                    values[:, :-1], tvalues[:, :-1], mask),
+                "corr_bootstrap": corrections.replay_baseline_mix(
+                    values[:, -1], tvalues[:, -1], mask),
+                "per_traj": True}
         total, metrics = losses_lib.impala_loss(
             cfg, logits[:, :-1], values[:, :-1], loss_batch,
-            impl=vtrace_impl)
+            impl=vtrace_impl, **replay)
         if arch_cfg.moe is not None:
             total = total + aux_coef * aux * (
                 batch["actions"].shape[0] * batch["actions"].shape[1])
@@ -67,339 +92,135 @@ def build_loss_fn(arch_cfg: ArchConfig, cfg: ImpalaConfig,
         return total, metrics
 
     return loss_fn
+
+
+def _grad_fn(loss_fn):
+    """``grad_step(params, batch, target_params=None) -> (grads, metrics)``:
+    gradients flow through ``params`` only, never the target."""
+    def grad_step(params, batch, target_params=None):
+        (_, metrics), grads = jax.value_and_grad(
+            loss_fn, has_aux=True)(params, batch, target_params)
+        return grads, metrics
+
+    return grad_step
+
+
+def _apply_fn(cfg: ImpalaConfig, optimizer: opt_lib.Optimizer,
+              mixed_precision: bool = False):
+    """``apply_step(params, opt_state, step, grads) -> (params, opt_state,
+    metrics)``: the learning rate, clip by global norm, optimizer, apply.
+
+    mixed_precision: the live params are bf16 and ``opt_state = {"opt":
+    <optimizer state>, "master": <f32 params>}``, so the cotangents (and
+    their cross-device reduction) are bf16 while RMSProp runs on the f32
+    master (§Perf B2: a cast inside the step reduces after the upcast)."""
+    lr_fn = opt_lib.linear_schedule(cfg.learning_rate, 0.0,
+                                    cfg.lr_anneal_steps)
+
+    def apply_step(params, opt_state, step, grads):
+        lr = lr_fn(step)
+        if mixed_precision:     # the update runs on the f32 master
+            grads = common_cast(grads, jnp.float32)
+            params, opt_state = opt_state["master"], opt_state["opt"]
+        grads, grad_norm = opt_lib.clip_by_global_norm(
+            grads, cfg.grad_clip_norm)
+        updates, opt_state = optimizer.update(grads, opt_state, params, lr)
+        params = opt_lib.apply_updates(params, updates)
+        if mixed_precision:
+            params, opt_state = (common_cast(params, jnp.bfloat16),
+                                 {"opt": opt_state, "master": params})
+        return params, opt_state, {"opt/grad_norm": grad_norm,
+                                   "opt/lr": lr}
+
+    return apply_step
+
+
+def _optimizer(cfg: ImpalaConfig, optimizer):
+    return optimizer if optimizer is not None else opt_lib.rmsprop(
+        decay=cfg.rmsprop_decay, eps=cfg.rmsprop_eps,
+        momentum=cfg.rmsprop_momentum)
+
+
+def batch_splits(mesh, batch: PyTree) -> bool:
+    """Whether the ``('data',)`` mesh splits ``batch``'s rows: every leaf
+    has as many, and ``sharding.rules.Rules`` shards a ``batch`` axis of
+    that size (its divisibility fallback replicates one it cannot)."""
+    leaves = jax.tree.leaves(batch)
+    rows = leaves[0].shape[0]
+    return all(x.shape[0] == rows for x in leaves) and \
+        Rules(mesh).spec(("batch",), (rows,))[0] is not None
 
 
 def build_train_step(arch_cfg: ArchConfig, cfg: ImpalaConfig,
                      num_actions: int,
                      optimizer: opt_lib.Optimizer = None,
-                     vtrace_impl: str = "auto",
+                     vtrace_impl: str = "auto", *, mesh=None,
                      mixed_precision: bool = False,
-                     ) -> Callable[..., Tuple[PyTree, PyTree, Dict]]:
-    """vtrace_impl: 'auto' picks the Pallas kernel on TPU and the scan
-    path elsewhere (``losses.resolve_vtrace_impl``); 'scan' / 'pallas' /
-    'reference' pin an implementation.
+                     ) -> Tuple[Callable, opt_lib.Optimizer]:
+    """The fused update ``train_step(params, opt_state, step, batch,
+    target_params=None) -> (params, opt_state, metrics)`` and its
+    optimizer (RMSProp from ``cfg`` unless given). ``target_params``
+    selects the replay loss and is never donated: jit with
+    ``donate_argnums=(0, 1)``. vtrace_impl: 'auto' picks the Pallas
+    kernel on TPU and the scan elsewhere (``losses.resolve_vtrace_impl``).
 
-    mixed_precision: the *live* params are bf16 leaves and the f32
-    master copy lives in the optimizer state — so the autodiff cotangents
-    (and the cross-device gradient reduction GSPMD inserts on them) are
-    bf16, halving grad-sync bytes (§Perf B2). RMSProp accumulates on the
-    f32 master. Note: casting to bf16 *inside* the step does NOT work —
-    GSPMD places the reduction after the upcast (measured, §Perf B2).
-
-    In this mode train_step expects ``params`` bf16 and
-    ``opt_state = {"opt": <optimizer state>, "master": <f32 params>}``.
+    ``mesh``: data parallelism over a ``('data',)`` mesh in one program.
+    ``shard_map`` splits the batch's rows, each device takes the
+    gradient of its shard, and gradients and scalar metrics are
+    ``lax.pmean``-ed in XLA before the replicated clip and update: the
+    update an N-learner group computes from the same shards (the
+    digest-triangle test). Rows the mesh cannot split (``batch_splits``,
+    at trace time) run replicated, where the pmean is an identity.
     """
-    if optimizer is None:
-        optimizer = opt_lib.rmsprop(decay=cfg.rmsprop_decay,
-                                    eps=cfg.rmsprop_eps,
-                                    momentum=cfg.rmsprop_momentum)
-    lr_fn = opt_lib.linear_schedule(cfg.learning_rate, 0.0,
-                                    cfg.lr_anneal_steps)
-    loss_fn = build_loss_fn(arch_cfg, cfg, num_actions, vtrace_impl)
+    optimizer = _optimizer(cfg, optimizer)
+    grad_fn = _grad_fn(build_loss_fn(arch_cfg, cfg, num_actions,
+                                     vtrace_impl))
+    apply_fn = _apply_fn(cfg, optimizer, mixed_precision)
 
-    def train_step(params, opt_state, step, batch):
-        (loss, metrics), grads = jax.value_and_grad(
-            loss_fn, has_aux=True)(params, batch)
-        lr = lr_fn(step)
-        if mixed_precision:
-            grads = common_cast(grads, jnp.float32)
-            grads, grad_norm = opt_lib.clip_by_global_norm(
-                grads, cfg.grad_clip_norm)
-            master = opt_state["master"]
-            updates, inner = optimizer.update(grads, opt_state["opt"],
-                                              master, lr)
-            master = opt_lib.apply_updates(master, updates)
-            params = common_cast(master, jnp.bfloat16)
-            opt_state = {"opt": inner, "master": master}
-        else:
-            grads, grad_norm = opt_lib.clip_by_global_norm(
-                grads, cfg.grad_clip_norm)
-            updates, opt_state = optimizer.update(grads, opt_state, params,
-                                                  lr)
-            params = opt_lib.apply_updates(params, updates)
-        metrics["opt/grad_norm"] = grad_norm
-        metrics["opt/lr"] = lr
-        return params, opt_state, metrics
+    def update(params, opt_state, step, batch, target_params):
+        grads, metrics = grad_fn(params, batch, target_params)
+        per_row = {k: metrics.pop(k) for k in _PER_ROW_METRICS
+                   if k in metrics}
+        if mesh is not None:
+            grads = jax.lax.pmean(grads, "data")
+            metrics = jax.lax.pmean(metrics, "data")
+        params, opt_state, opt_metrics = apply_fn(params, opt_state, step,
+                                                  grads)
+        return params, opt_state, {**metrics, **opt_metrics}, per_row
 
-    return train_step, optimizer
+    if mesh is None:
+        def train_step(params, opt_state, step, batch, target_params=None):
+            params, opt_state, metrics, per_row = update(
+                params, opt_state, step, batch, target_params)
+            return params, opt_state, {**metrics, **per_row}
 
+        return train_step, optimizer
 
-def build_replay_loss_fn(arch_cfg: ArchConfig, cfg: ImpalaConfig,
-                         num_actions: int, vtrace_impl: str = "auto",
-                         aux_coef: float = 0.01):
-    """Replay-aware loss: ``loss_fn(params, target_params, batch)``.
+    def local_step(params, opt_state, step, batch, target_params=None):
+        bspec = P("data") if batch_splits(mesh, batch) else P()
+        params, opt_state, metrics, per_row = shard_map(
+            update, mesh=mesh, in_specs=(P(), P(), P(), bspec, P()),
+            out_specs=(P(), P(), P(), bspec), check_vma=False)(
+                params, opt_state, step, batch, target_params)
+        return params, opt_state, {**metrics, **per_row}
 
-    ``batch['replay_mask']`` (B,) flags replayed rows. The IMPACT
-    recipe: replayed rows take the *target network's* values as the
-    V-trace correction baseline (``corrections.replay_baseline_mix``),
-    so K repeated consumptions chase a fixed target; online rows are
-    the exact standard loss. The per-trajectory |pg advantage| metric
-    (``vtrace/traj_adv_mag``) doubles as the replay priority signal.
-    """
-    from repro.core import corrections
-
-    def loss_fn(params, target_params, batch):
-        logits, values, aux = forward_trajectory(params, batch, arch_cfg,
-                                                 num_actions)
-        _, tvalues, _ = forward_trajectory(target_params, batch, arch_cfg,
-                                           num_actions)
-        mask = batch["replay_mask"]
-        corr_values = corrections.replay_baseline_mix(
-            values[:, :-1], tvalues[:, :-1], mask)
-        corr_bootstrap = corrections.replay_baseline_mix(
-            values[:, -1], tvalues[:, -1], mask)
-        loss_batch = {
-            "actions": batch["actions"],
-            "rewards": batch["rewards"],
-            "discounts": batch["discounts"],
-            "behaviour_logprob": batch["behaviour_logprob"],
-            "bootstrap_value": values[:, -1],
-        }
-        total, metrics = losses_lib.impala_loss(
-            cfg, logits[:, :-1], values[:, :-1], loss_batch,
-            impl=vtrace_impl, corr_values=corr_values,
-            corr_bootstrap=corr_bootstrap, per_traj=True)
-        if arch_cfg.moe is not None:
-            total = total + aux_coef * aux * (
-                batch["actions"].shape[0] * batch["actions"].shape[1])
-            metrics["loss/moe_aux"] = aux
-        return total, metrics
-
-    return loss_fn
-
-
-def build_replay_train_step(arch_cfg: ArchConfig, cfg: ImpalaConfig,
-                            num_actions: int,
-                            optimizer: opt_lib.Optimizer = None,
-                            vtrace_impl: str = "auto",
-                            ) -> Callable[..., Tuple[PyTree, PyTree, Dict]]:
-    """``train_step(params, target_params, opt_state, step, batch)`` —
-    the fused update for the replay path. Gradients flow only through
-    ``params`` (argnum 0); ``target_params`` is a read-only periodic
-    snapshot, so callers jit with ``donate_argnums=(0, 2)`` and keep
-    the target buffer alive across steps."""
-    if optimizer is None:
-        optimizer = opt_lib.rmsprop(decay=cfg.rmsprop_decay,
-                                    eps=cfg.rmsprop_eps,
-                                    momentum=cfg.rmsprop_momentum)
-    lr_fn = opt_lib.linear_schedule(cfg.learning_rate, 0.0,
-                                    cfg.lr_anneal_steps)
-    loss_fn = build_replay_loss_fn(arch_cfg, cfg, num_actions, vtrace_impl)
-
-    def train_step(params, target_params, opt_state, step, batch):
-        (loss, metrics), grads = jax.value_and_grad(
-            loss_fn, has_aux=True)(params, target_params, batch)
-        grads, grad_norm = opt_lib.clip_by_global_norm(
-            grads, cfg.grad_clip_norm)
-        lr = lr_fn(step)
-        updates, opt_state = optimizer.update(grads, opt_state, params, lr)
-        params = opt_lib.apply_updates(params, updates)
-        metrics["opt/grad_norm"] = grad_norm
-        metrics["opt/lr"] = lr
-        return params, opt_state, metrics
-
-    return train_step, optimizer
-
-
-def build_replay_grad_apply_steps(arch_cfg: ArchConfig, cfg: ImpalaConfig,
-                                  num_actions: int,
-                                  optimizer: opt_lib.Optimizer = None,
-                                  vtrace_impl: str = "auto"):
-    """Replay-aware split of ``build_grad_apply_steps``:
-    ``grad_step(params, target_params, batch)`` plus the unchanged
-    ``apply_step`` (clipping on the exchanged mean, identical update
-    math so group replicas stay digest-identical)."""
-    if optimizer is None:
-        optimizer = opt_lib.rmsprop(decay=cfg.rmsprop_decay,
-                                    eps=cfg.rmsprop_eps,
-                                    momentum=cfg.rmsprop_momentum)
-    lr_fn = opt_lib.linear_schedule(cfg.learning_rate, 0.0,
-                                    cfg.lr_anneal_steps)
-    loss_fn = build_replay_loss_fn(arch_cfg, cfg, num_actions, vtrace_impl)
-
-    def grad_step(params, target_params, batch):
-        (loss, metrics), grads = jax.value_and_grad(
-            loss_fn, has_aux=True)(params, target_params, batch)
-        return grads, metrics
-
-    def apply_step(params, opt_state, step, grads):
-        grads, grad_norm = opt_lib.clip_by_global_norm(
-            grads, cfg.grad_clip_norm)
-        lr = lr_fn(step)
-        updates, opt_state = optimizer.update(grads, opt_state, params,
-                                              lr)
-        params = opt_lib.apply_updates(params, updates)
-        return params, opt_state, {"opt/grad_norm": grad_norm,
-                                   "opt/lr": lr}
-
-    return grad_step, apply_step, optimizer
+    return local_step, optimizer
 
 
 def build_grad_apply_steps(arch_cfg: ArchConfig, cfg: ImpalaConfig,
                            num_actions: int,
                            optimizer: opt_lib.Optimizer = None,
                            vtrace_impl: str = "auto"):
-    """``train_step`` split at the gradient: ``grad_step(params, batch)
-    -> (grads, metrics)`` and ``apply_step(params, opt_state, step,
-    grads) -> (params, opt_state, metrics)`` — the shape a
-    data-parallel learner group needs, with a gradient exchange (mean
-    over the group) between the two halves.
-
-    Clipping happens in ``apply_step``, i.e. on the *exchanged mean*:
-    clip-after-average is the data-parallel-faithful choice (it equals
-    clipping the global-batch gradient a single learner with the
-    concatenated batch would have computed, up to the averaging
-    order), and it keeps every replica applying bit-identical updates
-    because they all clip the same broadcast buffer.
-
-    Composing the halves locally (``apply_step(params, opt_state, step,
-    grad_step(params, batch)[0])``) is mathematically the fused
-    ``train_step``; the fused path stays the single-learner default
-    because one jit program fuses better than two.
-    """
-    if optimizer is None:
-        optimizer = opt_lib.rmsprop(decay=cfg.rmsprop_decay,
-                                    eps=cfg.rmsprop_eps,
-                                    momentum=cfg.rmsprop_momentum)
-    lr_fn = opt_lib.linear_schedule(cfg.learning_rate, 0.0,
-                                    cfg.lr_anneal_steps)
-    loss_fn = build_loss_fn(arch_cfg, cfg, num_actions, vtrace_impl)
-
-    def grad_step(params, batch):
-        (loss, metrics), grads = jax.value_and_grad(
-            loss_fn, has_aux=True)(params, batch)
-        return grads, metrics
-
-    def apply_step(params, opt_state, step, grads):
-        grads, grad_norm = opt_lib.clip_by_global_norm(
-            grads, cfg.grad_clip_norm)
-        lr = lr_fn(step)
-        updates, opt_state = optimizer.update(grads, opt_state, params,
-                                              lr)
-        params = opt_lib.apply_updates(params, updates)
-        return params, opt_state, {"opt/grad_norm": grad_norm,
-                                   "opt/lr": lr}
-
-    return grad_step, apply_step, optimizer
-
-
-def build_spmd_train_step(arch_cfg: ArchConfig, cfg: ImpalaConfig,
-                          num_actions: int, mesh,
-                          optimizer: opt_lib.Optimizer = None,
-                          vtrace_impl: str = "auto",
-                          batch_replicated: bool = False,
-                          ) -> Callable[..., Tuple[PyTree, PyTree, Dict]]:
-    """Single-process data-parallel ``train_step`` over a ``('data',)``
-    mesh: ``shard_map`` shards the batch on the leading trajectory axis,
-    every device runs the backward pass on its shard, and the gradients
-    are mean-reduced in-XLA (``lax.pmean`` — one fused collective, no
-    host round-trip) before the replicated clip/update.
-
-    Clip-after-average matches ``build_grad_apply_steps``: with N
-    devices and per-shard sum-losses, the applied update is exactly
-    what an N-learner hub/spoke group computes from the same shards —
-    bit-identical on CPU, pinned by the digest-triangle test. Scalar
-    metrics are pmean'd (each shard's loss is a local sum, so the
-    reported loss is the per-shard mean, like a group member's).
-
-    ``batch_replicated=True`` builds the divisibility-fallback variant
-    (``sharding/rules.py`` replicates a leading dim the mesh cannot
-    split): every device sees the full batch, the pmean is an identity
-    over identical gradients, and the update equals the single-device
-    fused step. Callers jit the result with ``donate_argnums=(0, 1)``.
-    """
-    from jax.sharding import PartitionSpec as P
-
-    from repro.sharding.compat import shard_map
-
-    if optimizer is None:
-        optimizer = opt_lib.rmsprop(decay=cfg.rmsprop_decay,
-                                    eps=cfg.rmsprop_eps,
-                                    momentum=cfg.rmsprop_momentum)
-    lr_fn = opt_lib.linear_schedule(cfg.learning_rate, 0.0,
-                                    cfg.lr_anneal_steps)
-    loss_fn = build_loss_fn(arch_cfg, cfg, num_actions, vtrace_impl)
-
-    def local_step(params, opt_state, step, batch):
-        (loss, metrics), grads = jax.value_and_grad(
-            loss_fn, has_aux=True)(params, batch)
-        grads = jax.lax.pmean(grads, "data")
-        metrics = jax.lax.pmean(metrics, "data")
-        grads, grad_norm = opt_lib.clip_by_global_norm(
-            grads, cfg.grad_clip_norm)
-        lr = lr_fn(step)
-        updates, opt_state = optimizer.update(grads, opt_state, params,
-                                              lr)
-        params = opt_lib.apply_updates(params, updates)
-        metrics["opt/grad_norm"] = grad_norm
-        metrics["opt/lr"] = lr
-        return params, opt_state, metrics
-
-    bspec = P() if batch_replicated else P("data")
-    train_step = shard_map(local_step, mesh=mesh,
-                           in_specs=(P(), P(), P(), bspec),
-                           out_specs=(P(), P(), P()), check_vma=False)
-    return train_step, optimizer
-
-
-def build_spmd_replay_train_step(arch_cfg: ArchConfig, cfg: ImpalaConfig,
-                                 num_actions: int, mesh,
-                                 optimizer: opt_lib.Optimizer = None,
-                                 vtrace_impl: str = "auto",
-                                 batch_replicated: bool = False,
-                                 ) -> Callable[..., Tuple[PyTree, PyTree,
-                                                          Dict]]:
-    """SPMD variant of ``build_replay_train_step``:
-    ``train_step(params, target_params, opt_state, step, batch)`` with
-    the batch (``replay_mask`` included — it is per-row data, so it
-    shards with the rows) split over the ``('data',)`` mesh and the
-    gradients pmean'd in-XLA. The per-trajectory ``vtrace/traj_adv_mag``
-    metric is (B,)-shaped: each shard emits its local rows and the
-    shard_map output spec reassembles the global vector, so replay
-    re-prioritization sees every trajectory. Callers jit with
-    ``donate_argnums=(0, 2)`` (the target is a long-lived snapshot)."""
-    from jax.sharding import PartitionSpec as P
-
-    from repro.sharding.compat import shard_map
-
-    if optimizer is None:
-        optimizer = opt_lib.rmsprop(decay=cfg.rmsprop_decay,
-                                    eps=cfg.rmsprop_eps,
-                                    momentum=cfg.rmsprop_momentum)
-    lr_fn = opt_lib.linear_schedule(cfg.learning_rate, 0.0,
-                                    cfg.lr_anneal_steps)
-    loss_fn = build_replay_loss_fn(arch_cfg, cfg, num_actions, vtrace_impl)
-
-    def local_step(params, target_params, opt_state, step, batch):
-        (loss, metrics), grads = jax.value_and_grad(
-            loss_fn, has_aux=True)(params, target_params, batch)
-        metrics = dict(metrics)
-        traj_adv = metrics.pop("vtrace/traj_adv_mag")
-        grads = jax.lax.pmean(grads, "data")
-        metrics = jax.lax.pmean(metrics, "data")
-        grads, grad_norm = opt_lib.clip_by_global_norm(
-            grads, cfg.grad_clip_norm)
-        lr = lr_fn(step)
-        updates, opt_state = optimizer.update(grads, opt_state, params,
-                                              lr)
-        params = opt_lib.apply_updates(params, updates)
-        metrics["opt/grad_norm"] = grad_norm
-        metrics["opt/lr"] = lr
-        return params, opt_state, metrics, traj_adv
-
-    bspec = P() if batch_replicated else P("data")
-    smapped = shard_map(local_step, mesh=mesh,
-                        in_specs=(P(), P(), P(), P(), bspec),
-                        out_specs=(P(), P(), P(), bspec), check_vma=False)
-
-    def train_step(params, target_params, opt_state, step, batch):
-        params, opt_state, metrics, traj_adv = smapped(
-            params, target_params, opt_state, step, batch)
-        metrics = dict(metrics)
-        metrics["vtrace/traj_adv_mag"] = traj_adv
-        return params, opt_state, metrics
-
-    return train_step, optimizer
+    """``train_step`` split at the gradient, for a host gradient exchange
+    (mean over the group) between the halves: ``(grad_step, apply_step,
+    optimizer)`` as ``_grad_fn`` and ``_apply_fn`` describe them.
+    ``apply_step`` clips the *exchanged mean*, as one learner with the
+    concatenated batch would, and every replica applies bit-identical
+    updates because all clip the same broadcast buffer."""
+    optimizer = _optimizer(cfg, optimizer)
+    grad_fn = _grad_fn(build_loss_fn(arch_cfg, cfg, num_actions,
+                                     vtrace_impl))
+    return grad_fn, _apply_fn(cfg, optimizer), optimizer
 
 
 def opt_state_specs(param_specs: PyTree, cfg: ImpalaConfig,

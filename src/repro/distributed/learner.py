@@ -263,8 +263,8 @@ class _HostStager:
         tree = jax.tree.unflatten(treedef, bufs)
         if self._mesh is not None:
             # Rules divisibility fallback, staging edition: rows the
-            # mesh can't split land replicated so the P(None) compiled
-            # variant sees its expected sharding.
+            # mesh can't split land replicated, the sharding the step
+            # compiles for them (``core.learner.batch_splits``).
             from jax.sharding import NamedSharding, PartitionSpec
             out = jax.device_put(
                 tree, NamedSharding(self._mesh, PartitionSpec()))
@@ -328,14 +328,10 @@ class _HostStager:
         import jax
         from jax.sharding import NamedSharding, PartitionSpec as P
 
-        leaves = jax.tree.leaves(tree)
-        rows = leaves[0].shape[0]
-        if rows % self._n == 0 and \
-                all(x.shape[0] == rows for x in leaves):
-            sharding = NamedSharding(self._mesh, P("data"))
-        else:
-            sharding = NamedSharding(self._mesh, P())
-        return jax.device_put(tree, sharding)
+        from repro.core.learner import batch_splits
+
+        spec = P("data") if batch_splits(self._mesh, tree) else P()
+        return jax.device_put(tree, NamedSharding(self._mesh, spec))
 
 
 def _stack(items: List[TrajectoryItem],
@@ -459,123 +455,68 @@ class Learner:
             # data-parallel replicas must start identical, and
             # --learners 1 must bit-match the single-learner run
             params = pcommon.init_params(specs, jax.random.key(seed))
-        replay_on = icfg.replay_fraction > 0.0
         # what the loss will run, resolved now so telemetry shows it:
         # the implementation, and whether its Pallas kernel is
         # interpreted (never on a TPU unless forced)
         from repro.core.losses import resolve_loss_impl
         from repro.kernels.vtrace import resolve_interpret
-        impl = resolve_loss_impl(icfg, vtrace_impl, replay=replay_on)
+        impl = resolve_loss_impl(icfg, vtrace_impl,
+                                 replay=icfg.replay_fraction > 0.0)
         self.vtrace_resolved = {
             "impl": impl,
             "interpret": (impl in ("fused", "pallas")
                           and resolve_interpret())}
         spmd_on = exchange is not None and getattr(exchange, "in_xla",
                                                    False)
-        self._spmd_mesh = None
-        self._train_step_repl = None
-        if spmd_on:
-            from jax.sharding import NamedSharding, PartitionSpec as P
-
-            from repro.launch.mesh import make_data_mesh
-            from repro.sharding.rules import Rules
-
-            mesh = make_data_mesh(exchange.num_devices)
-            self._spmd_mesh = mesh
-            self._spmd_rules = Rules(mesh)
-            # the published snapshot is re-homed on one device so the
-            # inference service's forward doesn't run replicated over
-            # the whole mesh
-            self._spmd_publish_dev = jax.devices()[0]
-            # params (and below, opt state) live replicated over the
-            # mesh from the start: a donated shard_map step whose
-            # arguments already carry the compiled sharding never
-            # reshards on entry
-            params = jax.device_put(params, NamedSharding(mesh, P()))
-            if replay_on:
-                sharded, opt = learner_lib.build_spmd_replay_train_step(
-                    arch, icfg, num_actions, mesh,
-                    vtrace_impl=vtrace_impl)
-                repl, _ = learner_lib.build_spmd_replay_train_step(
-                    arch, icfg, num_actions, mesh, optimizer=opt,
-                    vtrace_impl=vtrace_impl, batch_replicated=True)
-                don = (0, 2)
-            else:
-                sharded, opt = learner_lib.build_spmd_train_step(
-                    arch, icfg, num_actions, mesh,
-                    vtrace_impl=vtrace_impl)
-                repl, _ = learner_lib.build_spmd_train_step(
-                    arch, icfg, num_actions, mesh, optimizer=opt,
-                    vtrace_impl=vtrace_impl, batch_replicated=True)
-                don = (0, 1)
-            if donate:
-                self._train_step = jax.jit(sharded, donate_argnums=don)
-                self._train_step_repl = jax.jit(repl, donate_argnums=don)
-            else:
-                self._train_step = jax.jit(sharded)
-                self._train_step_repl = jax.jit(repl)
-            self._grad_step = None
-            self._apply_step = None
-        elif exchange is None:
-            if replay_on:
-                # replay path: train_step(params, target_params,
-                # opt_state, step, batch) — the target (argnum 1) is a
-                # long-lived read-only snapshot, so only params and
-                # opt_state are donated
-                train_step, opt = learner_lib.build_replay_train_step(
-                    arch, icfg, num_actions, vtrace_impl=vtrace_impl)
-                if donate:
-                    train_step = jax.jit(train_step, donate_argnums=(0, 2))
-                else:
-                    train_step = jax.jit(train_step)
-            else:
-                train_step, opt = learner_lib.build_train_step(
-                    arch, icfg, num_actions, vtrace_impl=vtrace_impl)
-                if donate:
-                    train_step = jax.jit(train_step, donate_argnums=(0, 1))
-                else:
-                    train_step = jax.jit(train_step)
-            self._train_step = train_step
-            self._grad_step = None
-            self._apply_step = None
+        from repro.launch.mesh import make_data_mesh
+        self._spmd_mesh = (make_data_mesh(exchange.num_devices) if spmd_on
+                           else None)
+        # params and opt state are donated in every mode; the replay
+        # target, the step's trailing argument, is a long-lived snapshot
+        donated = (0, 1) if donate else ()
+        if exchange is None or spmd_on:
+            # one program per update, on one device or over the mesh
+            train_step, opt = learner_lib.build_train_step(
+                arch, icfg, num_actions, vtrace_impl=vtrace_impl,
+                mesh=self._spmd_mesh)
+            self._train_step = jax.jit(train_step, donate_argnums=donated)
+            self._grad_step = self._apply_step = None
         else:
-            if replay_on:
-                grad_step, apply_step, opt = \
-                    learner_lib.build_replay_grad_apply_steps(
-                        arch, icfg, num_actions, vtrace_impl=vtrace_impl)
-            else:
-                grad_step, apply_step, opt = \
-                    learner_lib.build_grad_apply_steps(
-                        arch, icfg, num_actions, vtrace_impl=vtrace_impl)
+            grad_step, apply_step, opt = learner_lib.build_grad_apply_steps(
+                arch, icfg, num_actions, vtrace_impl=vtrace_impl)
             self._train_step = None
             self._grad_step = jax.jit(grad_step)
-            if donate:
-                self._apply_step = jax.jit(apply_step,
-                                           donate_argnums=(0, 1))
-            else:
-                self._apply_step = jax.jit(apply_step)
+            self._apply_step = jax.jit(apply_step, donate_argnums=donated)
         # one jitted whole-tree device copy: the decoupling between the
         # learner's donated working tree and every reference that
         # escapes (store, service, on_update). XLA never aliases
         # non-donated outputs to inputs, so the copy's buffers are
         # independent by construction.
         self._snapshot = jax.jit(lambda tree: jax.tree.map(jnp.copy, tree))
-        self._params = params
         if initial_opt_state is not None:
             # checkpoint resume: restore the optimizer moments instead
             # of re-initializing — device_put so donation never aliases
             # the caller's (possibly mmapped) host buffers
-            self._opt_state = jax.device_put(initial_opt_state)
+            opt_state = jax.device_put(initial_opt_state)
         else:
-            self._opt_state = opt.init(params)
+            opt_state = opt.init(params)
         if spmd_on:
             from jax.sharding import NamedSharding, PartitionSpec as P
-            self._opt_state = jax.device_put(
-                self._opt_state, NamedSharding(self._spmd_mesh, P()))
-        self.store = ParameterStore(
-            self._spmd_publish(params) if spmd_on
-            else (self._snapshot(params) if donate else params),
-            version=start_step, wire_codec=wire_codec)
+
+            # params and opt state live replicated over the mesh from
+            # the start: a donated shard_map step whose arguments
+            # already carry the compiled sharding never reshards on
+            # entry
+            params, opt_state = jax.device_put(
+                (params, opt_state), NamedSharding(self._spmd_mesh, P()))
+            # the published snapshot is re-homed on one device so the
+            # inference service's forward doesn't run replicated over
+            # the whole mesh
+            self._spmd_publish_dev = jax.devices()[0]
+        self._params, self._opt_state = params, opt_state
+        self.store = ParameterStore(self._publish(params),
+                                    version=start_step,
+                                    wire_codec=wire_codec)
         self.start_step = start_step
         self.tracker = MultiTracker(num_actors, num_envs,
                                     slot_base=slot_base)
@@ -583,28 +524,7 @@ class Learner:
         self._stager = _HostStager(mesh=self._spmd_mesh)
         self._frames_per_traj = num_envs * icfg.unroll_length
         self._num_envs = num_envs
-        if replay_on:
-            # replay RNG identity is (seed, learner_id) — the
-            # fold_replay_seed discipline keeps group replicas on
-            # deterministic per-replica streams (and since every replica
-            # trains the same exchanged mean, giving them the SAME
-            # stream isn't needed for digest-identity; what matters is
-            # that each is deterministic across a restart)
-            self._replay = replay_lib.ReplayBuffer(
-                icfg.replay_capacity, seed=seed, learner_id=learner_id,
-                reuse_limit=icfg.replay_reuse,
-                priority=icfg.replay_priority)
-            self._fresh_max = max(1, int(round(
-                self._buckets[0] * (1.0 - icfg.replay_fraction))))
-            # IMPACT target: a periodic copy of the learner params
-            # supplies the V-trace baseline for replayed rows; synced
-            # every icfg.replay_target_period updates (a pure function
-            # of the update count, so group replicas sync in lockstep)
-            self._target_params = self._snapshot(params)
-        else:
-            self._replay = None
-            self._fresh_max = None
-            self._target_params = None
+        self._init_replay(seed)
         self._target_syncs = 0
         self.frames_trained = 0
         self.pool = None
@@ -642,8 +562,8 @@ class Learner:
         reg = self.obs_registry
         # SPMD only: how each update's batch reached the mesh
         # (``sharded`` + ``resharded`` = batches, counted by ``_stack``),
-        # and apart from those, how often the batch-replicated fallback
-        # step ran (every device computing the whole batch)
+        # and apart from those, how often the step ran on a batch the
+        # mesh could not split (every device computing the whole batch)
         self._spmd_batches = (
             {k: reg.counter(f"learner.spmd_batches.{k}")
              for k in ("sharded", "resharded", "replicated")}
@@ -810,32 +730,61 @@ class Learner:
             self.service.raise_errors()
 
     # ------------------------------------------------------------------
-    # SPMD mode helpers
+    # the update
 
-    def _spmd_publish(self, params):
-        """Snapshot + re-home on one device: the store (and through it
-        the inference service's jit and every actor pull) sees a plain
-        single-device tree, not an array replicated over the mesh —
-        a replicated forward would run on every mesh device."""
+    def _init_replay(self, seed: int) -> None:
+        """Replay state, all None without replay: the buffer, the cap
+        on fresh trajectories per batch, and the IMPACT target."""
+        icfg = self.icfg
+        self._replay = self._fresh_max = self._target_params = None
+        if icfg.replay_fraction <= 0.0:
+            return
+        # replay RNG identity is (seed, learner_id) — the
+        # fold_replay_seed discipline keeps group replicas on
+        # deterministic per-replica streams (and since every replica
+        # trains the same exchanged mean, giving them the SAME stream
+        # isn't needed for digest-identity; what matters is that each
+        # is deterministic across a restart)
+        self._replay = replay_lib.ReplayBuffer(
+            icfg.replay_capacity, seed=seed, learner_id=self.learner_id,
+            reuse_limit=icfg.replay_reuse, priority=icfg.replay_priority)
+        self._fresh_max = max(1, int(round(
+            self._buckets[0] * (1.0 - icfg.replay_fraction))))
+        # IMPACT target: a periodic copy of the learner params supplies
+        # the V-trace baseline for replayed rows; synced every
+        # icfg.replay_target_period updates (a pure function of the
+        # update count, so group replicas sync in lockstep)
+        self._target_params = self._snapshot(self._params)
+
+    @property
+    def _target_args(self) -> tuple:
+        """The replay target as the step's trailing argument; empty
+        without replay."""
+        return () if self._target_params is None else (self._target_params,)
+
+    def _with_replay_mask(self, batch, n_replayed: int, n_items: int):
+        """The batch with its replay mask (replayed rows sit first) riding
+        as data, so every bucket keeps a single compiled program; the
+        batch itself without replay."""
+        if self._replay is None:
+            return batch
+        mask = np.zeros(n_items * self._num_envs, np.float32)
+        mask[:n_replayed * self._num_envs] = 1.0
+        return {**batch, "replay_mask": mask}
+
+    def _publish(self, params):
+        """What escapes the learner after an update: a decoupled snapshot
+        when the working tree is donated. SPMD re-homes it on one device,
+        so the store (and through it the inference service's jit and
+        every actor pull) sees a plain single-device tree, not an array
+        replicated over the mesh — a replicated forward would run on
+        every mesh device."""
         import jax
-        return jax.device_put(self._snapshot(params),
-                              self._spmd_publish_dev)
-
-    def _spmd_step_for(self, batch):
-        """Pick the compiled variant for this batch's leading row count
-        via the sharding rules: rows the ``('data',)`` mesh divides run
-        the batch-sharded step; anything else (the Rules divisibility
-        fallback, ``P(None)``) runs the batch-replicated variant —
-        every device computes the full-batch gradient and the pmean is
-        an identity, so semantics match the fused single step exactly."""
-        import jax
-
-        leaves = jax.tree.leaves(batch)
-        rows = leaves[0].shape[0]
-        if all(x.shape[0] == rows for x in leaves) and \
-                self._spmd_rules.spec(("batch",), (rows,))[0] is not None:
-            return self._train_step
-        return self._train_step_repl
+        if self.donate:
+            params = self._snapshot(params)
+        if self._spmd_mesh is not None:
+            params = jax.device_put(params, self._spmd_publish_dev)
+        return params
 
     def _warm(self, params, opt_state) -> None:
         """Pre-compile the train step for every batch bucket on
@@ -849,52 +798,22 @@ class Learner:
             self._raise_worker_errors()
             first = self.queue.get(timeout=0.5)
         for b in self._buckets:
-            if self._spmd_mesh is not None:
-                # stage through the sharded stager so each bucket's
-                # compile sees the exact input sharding of steady state
-                warm = _stack([first] * b, self._stager)
+            # staged as steady state stages it, so each bucket's compile
+            # sees its input sharding; an all-zero replay mask compiles
+            # the one program each bucket ever needs
+            warm = self._with_replay_mask(_stack([first] * b, self._stager),
+                                          0, b)
+            if self._grad_step is None:
+                out = self._train_step(self._snapshot(params),
+                                       self._snapshot(opt_state),
+                                       jnp.int32(0), warm,
+                                       *self._target_args)
             else:
-                warm = _stack([first] * b) if b > 1 else first.data
-            if self._replay is not None:
-                # the replay mask is batch DATA (not a static shape), so
-                # an all-zero warm mask compiles the one program each
-                # bucket ever needs
-                warm = dict(warm)
-                warm["replay_mask"] = np.zeros(b * self._num_envs,
-                                               np.float32)
-            if self._spmd_mesh is not None:
-                step_fn = self._spmd_step_for(warm)
-                if self._replay is not None:
-                    out = step_fn(self._snapshot(params),
-                                  self._target_params,
-                                  self._snapshot(opt_state),
-                                  jnp.int32(0), warm)
-                else:
-                    out = step_fn(self._snapshot(params),
-                                  self._snapshot(opt_state),
-                                  jnp.int32(0), warm)
-                jax.block_until_ready(out[0])
-            elif self._exchange is None:
-                if self._replay is not None:
-                    out = self._train_step(self._snapshot(params),
-                                           self._target_params,
-                                           self._snapshot(opt_state),
-                                           jnp.int32(0), warm)
-                else:
-                    out = self._train_step(self._snapshot(params),
-                                           self._snapshot(opt_state),
-                                           jnp.int32(0), warm)
-                jax.block_until_ready(out[0])   # compile only; discard
-            else:
-                if self._replay is not None:
-                    grads, _ = self._grad_step(params, self._target_params,
-                                               warm)
-                else:
-                    grads, _ = self._grad_step(params, warm)
+                grads, _ = self._grad_step(params, warm, *self._target_args)
                 out = self._apply_step(self._snapshot(params),
                                        self._snapshot(opt_state),
                                        jnp.int32(0), grads)
-                jax.block_until_ready(out[0])
+            jax.block_until_ready(out[0])   # compile only; discard
         self.queue.requeue_front(first)
 
     def _update_once(self, batch, jnp, jax, timings=None):
@@ -933,59 +852,37 @@ class Learner:
         return published, metrics
 
     def _step(self, batch, jnp, jax):
-        """The update itself: fused when alone, split
-        backward/exchange/apply when grouped. Returns (params to
-        publish, metrics, the version to publish them at — None for the
-        store's next) or None when the exchange shut down."""
-        if self._spmd_mesh is not None:
-            # SPMD: the whole group update is ONE donated shard_map
-            # dispatch — backward, in-XLA pmean, optimizer. Nothing
-            # crosses the host, so the exchange only delegates the
-            # version number and books the round.
+        """The update itself: one program when alone or SPMD, split
+        backward/exchange/apply under a host exchange. Returns (params
+        to publish, metrics, the version to publish them at — None for
+        the store's next) or None when the exchange shut down."""
+        if self._grad_step is None:
             t0 = time.monotonic()
-            step_fn = self._spmd_step_for(batch)
-            if step_fn is self._train_step_repl:
+            self._params, self._opt_state, metrics = self._train_step(
+                self._params, self._opt_state, jnp.int32(self.updates),
+                batch, *self._target_args)
+            if self._spmd_mesh is None:
+                return self._publish(self._params), metrics, None
+            # SPMD: the whole group update was that one donated dispatch
+            # — backward, in-XLA pmean, optimizer. Nothing crosses the
+            # host, so the exchange only delegates the version number
+            # and books the round.
+            from repro.core.learner import batch_splits
+            if not batch_splits(self._spmd_mesh, batch):
                 self._spmd_batches["replicated"].inc()
-            if self._replay is not None:
-                self._params, self._opt_state, metrics = step_fn(
-                    self._params, self._target_params, self._opt_state,
-                    jnp.int32(self.updates), batch)
-            else:
-                self._params, self._opt_state, metrics = step_fn(
-                    self._params, self._opt_state,
-                    jnp.int32(self.updates), batch)
-            reduced = self._exchange.allreduce((),
-                                               round_idx=self.updates)
+            reduced = self._exchange.allreduce((), round_idx=self.updates)
             if reduced is None:
                 return None                 # exchange shutting down
-            _, version = reduced
-            published = (self._spmd_publish(self._params) if self.donate
-                         else jax.device_put(self._params,
-                                             self._spmd_publish_dev))
+            published = self._publish(self._params)
             # grad_norm is computed from the pmean'd mean: waiting on it
             # waits on the collective completing on every shard, so the
             # observed round latency is the real all-reduce+apply time
             jax.block_until_ready(metrics["opt/grad_norm"])
             self._exchange.observe_round_s(time.monotonic() - t0,
                                            round_idx=self.updates)
-            return published, metrics, version
-        if self._exchange is None:
-            if self._replay is not None:
-                self._params, self._opt_state, metrics = self._train_step(
-                    self._params, self._target_params, self._opt_state,
-                    jnp.int32(self.updates), batch)
-            else:
-                self._params, self._opt_state, metrics = self._train_step(
-                    self._params, self._opt_state, jnp.int32(self.updates),
-                    batch)
-            published = (self._snapshot(self._params) if self.donate
-                         else self._params)
-            return published, metrics, None
-        if self._replay is not None:
-            grads, metrics = self._grad_step(self._params,
-                                             self._target_params, batch)
-        else:
-            grads, metrics = self._grad_step(self._params, batch)
+            return published, metrics, reduced[1]
+        grads, metrics = self._grad_step(self._params, batch,
+                                         *self._target_args)
         leaves, treedef = jax.tree.flatten(grads)
         # np.asarray forces the backward pass and lands the gradient
         # leaves host-side (views on the CPU backend, copies elsewhere)
@@ -997,11 +894,7 @@ class Learner:
         mean = jax.tree.unflatten(treedef, list(mean_leaves))
         self._params, self._opt_state, ametrics = self._apply_step(
             self._params, self._opt_state, jnp.int32(self.updates), mean)
-        metrics = dict(metrics)
-        metrics.update(ametrics)
-        published = (self._snapshot(self._params) if self.donate
-                     else self._params)
-        return published, metrics, version
+        return self._publish(self._params), {**metrics, **ametrics}, version
 
     def _sample_replay(self, num_fresh: int, version_now: int):
         """Plan and draw the replayed top-up for a batch of
@@ -1130,16 +1023,9 @@ class Learner:
                         self._stager.last_device_put_s = 0.0
                     batch = _stack(train_items, self._stager,
                                    self._spmd_batches)
-                    if self._replay is not None:
-                        # replayed rows sit FIRST in the stacked batch;
-                        # the mask rides as data so every bucket keeps a
-                        # single compiled program
-                        n_rep = len(samples) if samples else 0
-                        mask = np.zeros(len(train_items) * self._num_envs,
-                                        np.float32)
-                        mask[:n_rep * self._num_envs] = 1.0
-                        batch = dict(batch)
-                        batch["replay_mask"] = mask
+                    batch = self._with_replay_mask(
+                        batch, len(samples) if samples else 0,
+                        len(train_items))
                 t_stk = time.monotonic() if want_t else 0.0
                 if self._profile is not None:
                     self._profile.on_step(self.updates)

@@ -154,6 +154,8 @@ def test_impala_deep_donated_train_step_compiles_for_v5e(
         _abstract_batch(chase, arch, b, one_chip))
     compiled = lowered.compile()
     hlo = compiled.as_text()
+    # the name step.device_ms finds the step's device time by
+    assert hlo.startswith("HloModule jit_train_step"), hlo[:80]
     # the fused loss/V-trace kernel is in the step, compiled by Mosaic
     assert "tpu_custom_call" in hlo
     mem = _record(compiled, f"impala-deep train step B={b}")
@@ -169,8 +171,8 @@ def test_spmd_train_step_compiles_on_v5e_4_device_mesh(
     mesh = auto_mesh((4,), ("data",), devices=topo.devices)
     assert len(set(mesh.devices.flat)) == 4
     arch, icfg = _agent(chase)
-    step, opt = learner_lib.build_spmd_train_step(
-        arch, icfg, chase.num_actions, mesh, vtrace_impl="fused")
+    step, opt = learner_lib.build_train_step(
+        arch, icfg, chase.num_actions, vtrace_impl="fused", mesh=mesh)
     params, opt_state = _params_and_opt(arch, chase, icfg, opt.init)
     repl = NamedSharding(mesh, P())
     b = NUM_ENVS * MAX_TRAJS
@@ -180,6 +182,8 @@ def test_spmd_train_step_compiles_on_v5e_4_device_mesh(
         _abstract_batch(chase, arch, b,
                         NamedSharding(mesh, P("data")))).compile()
     hlo = compiled.as_text()
+    # the name step.device_ms finds the step's device time by
+    assert hlo.startswith("HloModule jit_local_step"), hlo[:80]
     assert "tpu_custom_call" in hlo
     # the gradient mean is an in-XLA collective over the mesh
     assert "all-reduce" in hlo

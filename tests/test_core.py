@@ -301,3 +301,64 @@ def test_mixed_precision_step_matches_f32():
     assert jax.tree.leaves(p16b)[0].dtype == jnp.bfloat16
     assert jax.tree.leaves(os2["master"])[0].dtype == jnp.float32
     assert abs(float(m32["loss/total"]) - float(mmp["loss/total"])) < 0.05
+
+
+@pytest.mark.parametrize("replay", [False, True])
+def test_fused_step_equals_apply_of_grad_step(replay):
+    """Both step builders compose the same gradient and apply functions:
+    three fused updates and three ``apply_step(grad_step)`` updates land
+    on the same params, with and without the replay target."""
+    from repro.configs.registry import get_smoke_config
+    from repro.core import learner as learner_lib
+    from repro.data.envs import make_env
+    from repro.models import backbone as bb
+    from repro.models import common as pc
+
+    env = make_env("bandit")
+    a = env.num_actions
+    arch = get_smoke_config("impala_shallow").replace(image_hw=env.image_hw)
+    icfg = ImpalaConfig(num_actions=a, unroll_length=4, learning_rate=1e-3,
+                        rmsprop_eps=0.01)
+    specs = bb.backbone_specs(arch, a)
+    params = pc.init_params(specs, jax.random.key(0))
+    target = ((pc.init_params(specs, jax.random.key(1)),) if replay
+              else ())
+    rng = np.random.default_rng(0)
+    b, t = 2, 4
+
+    def batch():
+        out = {
+            "obs_image": rng.random((b, t + 1) + env.image_hw
+                                    ).astype(np.float32),
+            "last_action": rng.integers(0, a, (b, t + 1)).astype(np.int32),
+            "last_reward": rng.random((b, t + 1)).astype(np.float32),
+            "done_in": np.zeros((b, t + 1), np.bool_),
+            "actions": rng.integers(0, a, (b, t)).astype(np.int32),
+            "rewards": rng.standard_normal((b, t)).astype(np.float32),
+            "discounts": np.full((b, t), 0.99, np.float32),
+            "behaviour_logprob": np.full((b, t), -1.0, np.float32),
+        }
+        if replay:
+            out["replay_mask"] = np.array([1.0, 0.0], np.float32)
+        return out
+
+    fused, opt = learner_lib.build_train_step(arch, icfg, a,
+                                              vtrace_impl="scan")
+    grad_step, apply_step, opt2 = learner_lib.build_grad_apply_steps(
+        arch, icfg, a, vtrace_impl="scan")
+    fused, grad_step, apply_step = (jax.jit(fused), jax.jit(grad_step),
+                                    jax.jit(apply_step))
+    pf, of = params, opt.init(params)
+    ps, os_ = params, opt2.init(params)
+    for i in range(3):
+        bt = batch()
+        pf, of, mf = fused(pf, of, jnp.int32(i), bt, *target)
+        grads, ms = grad_step(ps, bt, *target)
+        ps, os_, _ = apply_step(ps, os_, jnp.int32(i), grads)
+        assert float(mf["loss/total"]) == pytest.approx(
+            float(ms["loss/total"]), rel=1e-6)
+        assert ("vtrace/traj_adv_mag" in mf) == replay
+    for x, y in zip(jax.tree.leaves(pf), jax.tree.leaves(ps)):
+        x, y = np.asarray(x, np.float64), np.asarray(y, np.float64)
+        assert np.max(np.abs(x - y)) <= 1e-6 * max(np.max(np.abs(y)),
+                                                   1e-30)

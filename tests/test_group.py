@@ -676,8 +676,8 @@ dist, _ = run_group({0: [r[0] for r in rounds],
 
 # ---- leg C: spmd shard_map step on the real ('data',) mesh
 mesh = make_data_mesh(2)
-spmd_step, opt3 = learner_lib.build_spmd_train_step(arch, icfg, A, mesh,
-                                                    vtrace_impl="scan")
+spmd_step, opt3 = learner_lib.build_train_step(arch, icfg, A,
+                                               vtrace_impl="scan", mesh=mesh)
 spmd = jax.jit(spmd_step)
 rep = NamedSharding(mesh, P())
 devs = list(mesh.devices.flatten())

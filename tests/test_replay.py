@@ -242,22 +242,21 @@ def test_replay_loss_mask_zero_matches_standard_loss():
         "behaviour_logprob": np.log(
             np.full((b, t), 1.0 / env.num_actions, np.float32)),
     }
-    std = learner_lib.build_loss_fn(arch, icfg, env.num_actions)
-    rep = learner_lib.build_replay_loss_fn(arch, icfg, env.num_actions)
-    total_std, m_std = std(params, batch)
+    loss = learner_lib.build_loss_fn(arch, icfg, env.num_actions)
+    total_std, m_std = loss(params, batch)
     rb = dict(batch)
     rb["replay_mask"] = np.zeros(b, np.float32)
-    total_rep, m_rep = rep(params, other, rb)
+    total_rep, m_rep = loss(params, rb, other)
     assert float(total_rep) == pytest.approx(float(total_std), rel=1e-6)
     # the per-trajectory priority signal rides the metrics, (B,)-shaped
     assert m_rep["vtrace/traj_adv_mag"].shape == (b,)
     # mask=1 really routes the target values into the correction
     rb1 = dict(rb)
     rb1["replay_mask"] = np.ones(b, np.float32)
-    total_tgt, _ = rep(params, other, rb1)
+    total_tgt, _ = loss(params, rb1, other)
     assert float(total_tgt) != pytest.approx(float(total_std), rel=1e-6)
     # ... and a target identical to the online params is a no-op
-    total_same, _ = rep(params, params, rb1)
+    total_same, _ = loss(params, rb1, params)
     assert float(total_same) == pytest.approx(float(total_std), rel=1e-5)
 
 
